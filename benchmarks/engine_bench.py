@@ -187,8 +187,7 @@ def time_whole_run(method, engine, clients, adapter, batch_size,
         hc = hlo_cost(strat)
         if hc is not None:
             row["hlo_compile_seconds"] = hc["compile_seconds"]
-            if "memory" in hc:
-                row["memory"] = hc["memory"]
+            row["memory"] = hc["memory"]
     return row
 
 
@@ -245,8 +244,7 @@ def time_raw_grid(method, clients, adapter, batch_size, run_epochs, reps,
         hc = hlo_cost(rec["strat"])
         if hc is not None:
             row["hlo_compile_seconds"] = hc["compile_seconds"]
-            if "memory" in hc:
-                row["memory"] = hc["memory"]
+            row["memory"] = hc["memory"]
         rows.append(row)
     return rows
 
@@ -551,4 +549,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -18,9 +18,9 @@ engine, int8 cut-layer transport on the split family), runs each once so
 writes the per-strategy compute/memory/collective cost table to
 ``benchmarks/results/BENCH_roofline.json``.
 
-Hardware peaks come from ``launch.dryrun.HW_TABLE``; ``--hw`` selects the
-row (default: whatever matches the current backend, so a CPU smoke run
-labels its roofline as ``cpu_host`` instead of pretending TPU ceilings).
+Hardware peaks come from ``launch.dryrun.HW_TABLE``, keyed by device kind;
+``--hw`` selects the row (default: the first device's kind, which must be
+in the table — a CPU run names the nominal row with ``--hw cpu_host``).
 """
 
 from __future__ import annotations
@@ -178,9 +178,8 @@ def strategy_roofline(methods=None, n_clients=3, train_per_client=16,
                "t_compute_s": rf["t_compute"], "t_memory_s": rf["t_memory"],
                "t_collective_s": rf["t_collective"],
                "dominant": rf["dominant"],
-               "compile_seconds": cost["compile_seconds"]}
-        if "memory" in cost:
-            row["memory"] = cost["memory"]
+               "compile_seconds": cost["compile_seconds"],
+               "memory": cost["memory"]}
         rows.append(row)
     return {"hw": hw_name, "peaks": peaks, "n_clients": n_clients,
             "train_per_client": train_per_client, "batch_size": batch_size,
@@ -194,7 +193,8 @@ def main():
     ap.add_argument("--mode", default="strategies",
                     choices=["strategies", "dryrun"])
     ap.add_argument("--hw", default=None, choices=list(HW_TABLE),
-                    help="hardware peaks row (default: match the backend)")
+                    help="hardware peaks row (default: the device kind; "
+                         "CPU runs pass cpu_host)")
     ap.add_argument("--clients", type=int, default=3)
     ap.add_argument("--train-per-client", type=int, default=16)
     ap.add_argument("--batch", type=int, default=4)
@@ -243,4 +243,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

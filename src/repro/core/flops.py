@@ -23,8 +23,6 @@ TRAIN_FACTOR = 3.0     # fwd + bwd
 def flops_of(fn, *args) -> float:
     """HLO FLOPs of fn(*args) on the current backend."""
     cost = jax.jit(fn).lower(*args).compile().cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0]
     return float(cost.get("flops", 0.0))
 
 

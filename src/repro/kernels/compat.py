@@ -1,19 +1,16 @@
-"""Pallas-TPU API compatibility across jax versions.
+"""The one interpret-mode switch shared by every kernel's ops layer.
 
-``pltpu.TPUCompilerParams`` (jax <= 0.4.x) was renamed to
-``pltpu.CompilerParams`` in later releases; resolve whichever exists once.
-
-``INTERPRET`` is the shared interpret-mode default for every kernel's ops
-layer: off-TPU backends (CPU CI, GPU dev boxes) run the kernels through the
-Pallas interpreter so the whole suite stays runnable anywhere.
+``INTERPRET`` is True when the process is not on the TPU backend: CPU
+test runs then execute the kernels through the Pallas interpreter.  On the
+chip it is False and every kernel lowers to a ``tpu_custom_call``;
+``chip_smoke.py`` refuses to run unless that holds.
 """
 
 from __future__ import annotations
 
 import jax
-import jax.experimental.pallas.tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+from jax.experimental.pallas.tpu import CompilerParams
 
 INTERPRET = jax.default_backend() != "tpu"
+
+__all__ = ["INTERPRET", "CompilerParams"]

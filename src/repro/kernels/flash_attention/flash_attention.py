@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels.compat import INTERPRET, CompilerParams
 
 NEG_INF = -1e30
 
@@ -84,7 +84,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 
 def flash_attention_pallas(q, k, v, *, causal=True, block_q=128, block_k=128,
-                           interpret=True):
+                           interpret=INTERPRET):
     """q: (B, H, S, D); k, v: (B, KV, S, D).  Returns (B, H, S, D)."""
     b, h, s, d = q.shape
     kv, t = k.shape[1], k.shape[2]

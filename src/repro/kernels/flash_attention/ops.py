@@ -11,9 +11,8 @@ from functools import partial
 
 import jax
 
+from repro.kernels.compat import INTERPRET
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
@@ -23,5 +22,5 @@ def flash_attention(q, k, v, causal=True, block_q=128, block_k=128):
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     out = flash_attention_pallas(qt, kt, vt, causal=causal, block_q=block_q,
-                                 block_k=block_k, interpret=_INTERPRET)
+                                 block_k=block_k, interpret=INTERPRET)
     return out.transpose(0, 2, 1, 3)

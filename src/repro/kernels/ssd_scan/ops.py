@@ -7,9 +7,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.compat import INTERPRET
 from repro.kernels.ssd_scan.ssd_scan import ssd_chunk_kernel
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("chunk",))
@@ -28,7 +27,7 @@ def ssd(x, dt, A, B, C, chunk):
     Cc = C.reshape(b, nc, q, g, n)
 
     y_intra, states, dte, dfs = ssd_chunk_kernel(
-        xbar, la, Bc, Cc, interpret=_INTERPRET)
+        xbar, la, Bc, Cc, interpret=INTERPRET)
 
     # inter-chunk recurrence (sequential over nc, tiny (h,n,p) carry)
     a_last = jnp.exp(la.sum(axis=2))                        # (b, nc, h)

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels.compat import INTERPRET, CompilerParams
 
 NEG_INF = -1e30
 
@@ -58,7 +58,7 @@ def _kernel(xbar_ref, la_ref, b_ref, c_ref, y_ref, st_ref, dte_ref, dfs_ref):
     dfs_ref[0, 0, :, :] = dfs
 
 
-def ssd_chunk_kernel(xbar, la, B, C, *, interpret=True):
+def ssd_chunk_kernel(xbar, la, B, C, *, interpret=INTERPRET):
     """xbar: (b, nc, q, h, p)  la: (b, nc, q, h)  B, C: (b, nc, q, g, n).
     Returns y_intra (b,nc,q,h,p) f32, states (b,nc,h,n,p) f32,
             dte (b,nc,q,h) f32, dfs (b,nc,q,h) f32."""

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable e) + roofline source data (deliverable g).
 
 For every (architecture × input shape × mesh) combination this lowers and
@@ -21,6 +18,7 @@ Usage:
 
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -39,21 +37,28 @@ from repro.launch.train import (get_axes_tree, init_sflv3_params,
 from repro.models.transformer import TransformerLM
 from repro.serving.engine import make_decode_step, make_prefill_step
 
-# Per-chip/-host hardware peaks the roofline terms divide by.  Keyed so a
-# CPU smoke run can label its roofline honestly instead of pretending its
-# numbers sit on a TPU's ceilings; ``cpu_host`` is a nominal modern server
-# (AVX-512 f32 FMA, dual-socket DDR5, 100 GbE interconnect).
+# Per-chip hardware peaks the roofline terms divide by, keyed by
+# ``jax.devices()[0].device_kind``.  Source for "TPU v5 lite" (TPU v5e):
+# Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s (200e9 B/s) chip-to-chip interconnect.
+# ``cpu_host`` is a nominal modern server (AVX-512 f32 FMA, dual-socket
+# DDR5, 100 GbE); no device reports it, so CPU callers name it explicitly
+# (``--hw cpu_host``).
+V5E = "TPU v5 lite"
 HW_TABLE = {
-    "tpu_v5e": {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+    V5E: {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 200e9},
     "cpu_host": {"peak_flops": 2e12, "hbm_bw": 200e9, "ici_bw": 12.5e9},
 }
-HW = HW_TABLE["tpu_v5e"]              # historical default (TPU v5e, per chip)
 
 
 def default_hw() -> str:
-    """The HW_TABLE key matching the current jax backend."""
-    import jax
-    return "tpu_v5e" if jax.default_backend() == "tpu" else "cpu_host"
+    """The HW_TABLE key of the first local device; a device kind missing
+    from the table is an error, never a silent default."""
+    kind = jax.devices()[0].device_kind
+    if kind not in HW_TABLE:
+        raise KeyError(f"no peaks for device kind {kind!r} in HW_TABLE "
+                       f"(known: {sorted(HW_TABLE)}); pass --hw explicitly")
+    return kind
 
 _DT_BYTES = {"f64": 8, "s64": 8, "u64": 8, "c64": 8, "f32": 4, "s32": 4,
              "u32": 4, "bf16": 2, "f16": 2, "s16": 2, "u16": 2, "s8": 1,
@@ -201,8 +206,6 @@ def run_combo(arch_id: str, shape_name: str, multi_pod: bool,
                           "generated_code_size_in_bytes"):
                     rec[k] = int(getattr(mem, k, 0) or 0)
             cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):
-                cost = cost[0]
             # raw cost_analysis counts while-loop bodies ONCE (verified);
             # hlo_analysis re-derives costs with trip-count multipliers.
             rec["hlo_flops_raw"] = float(cost.get("flops", 0.0))
@@ -229,14 +232,13 @@ def run_combo(arch_id: str, shape_name: str, multi_pod: bool,
     return rec
 
 
-def roofline_terms(rec: dict, mesh_chips: int, hw=None) -> dict:
+def roofline_terms(rec: dict, mesh_chips: int, hw=V5E) -> dict:
     """The three roofline terms in seconds (single-pod table; DESIGN.md §5).
     cost_analysis FLOPs/bytes are per-device program numbers on the
     partitioned module; collective bytes are per-device link traffic.
-    ``hw``: an ``HW_TABLE`` key or peaks dict (default: TPU v5e)."""
-    if hw is None:
-        hw = HW
-    elif isinstance(hw, str):
+    ``hw``: an ``HW_TABLE`` key or peaks dict (default: TPU v5e, the
+    chip the dry-run's production meshes describe)."""
+    if isinstance(hw, str):
         hw = HW_TABLE[hw]
     coll = rec.get("collectives", {})
     coll_b = sum(v for k, v in coll.items() if k != "counts")
@@ -251,6 +253,9 @@ def roofline_terms(rec: dict, mesh_chips: int, hw=None) -> dict:
 
 
 def main():
+    # the production meshes need 512 virtual host devices; set before the
+    # first backend query (only this entry point wants them)
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

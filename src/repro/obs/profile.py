@@ -4,8 +4,8 @@ Three independent captures feeding the per-strategy cost summary (the
 ROADMAP's roofline item):
 
   * ``jax_profile(dir)``: context manager around ``jax.profiler`` device
-    tracing (TensorBoard/Perfetto dump) — best-effort, a no-op when the
-    profiler is unavailable in the environment.
+    tracing (TensorBoard/Perfetto dump); raises when the profiler cannot
+    start.
   * ``hlo_cost(strategy)``: every compiled ``Strategy.run`` stashes its
     jitted whole-run callable + concrete args as
     ``strategy._last_run_invocation``; this re-lowers it (timed — on a
@@ -29,24 +29,15 @@ import time
 @contextlib.contextmanager
 def jax_profile(trace_dir):
     """Wrap a block in ``jax.profiler`` device tracing writing to
-    ``trace_dir`` (viewable in TensorBoard / Perfetto).  Best-effort: when
-    the profiler cannot start (no backend support, already active) the
-    block simply runs untraced."""
+    ``trace_dir`` (viewable in TensorBoard / Perfetto).  A profiler that
+    cannot start or stop raises: a trace that silently never gets written
+    would leave the run looking traced when it was not."""
     import jax
-    started = False
-    try:
-        jax.profiler.start_trace(str(trace_dir))
-        started = True
-    except Exception:
-        pass
+    jax.profiler.start_trace(str(trace_dir))
     try:
         yield
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        jax.profiler.stop_trace()
 
 
 def hlo_cost(strategy) -> dict | None:
@@ -66,20 +57,12 @@ def hlo_cost(strategy) -> dict | None:
     # compiler's own buffer-assignment view: temp (peak scratch), argument,
     # output and donation-aliased bytes — the donated whole-run programs
     # show their HBM saving in alias_size vs argument_size
-    mem = {}
-    try:
-        ma = compiled.memory_analysis()
-        for f in ("temp_size_in_bytes", "argument_size_in_bytes",
-                  "output_size_in_bytes", "alias_size_in_bytes",
-                  "generated_code_size_in_bytes"):
-            v = getattr(ma, f, None)
-            if v is not None:
-                mem[f] = int(v)
-    except Exception:
-        pass                       # backend without memory_analysis support
-    if mem:
-        cost = {**cost, "memory": mem}
-    return {"compile_seconds": compile_s, **cost}
+    ma = compiled.memory_analysis()
+    mem = {f: int(getattr(ma, f))
+           for f in ("temp_size_in_bytes", "argument_size_in_bytes",
+                     "output_size_in_bytes", "alias_size_in_bytes",
+                     "generated_code_size_in_bytes")}
+    return {"compile_seconds": compile_s, **cost, "memory": mem}
 
 
 def cost_summary(strategy, wall_seconds: float | None = None,

@@ -4,6 +4,7 @@ subprocess 512-device mesh construction check."""
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -56,8 +57,6 @@ def test_analyze_counts_scan_iterations():
     assert ana["flops"] == 6 * 2 * 64 ** 3
     # raw cost_analysis counts the body once — the analyzer must not
     ca = jax.jit(scan6).lower(x, ws).compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):      # older jax returns [dict]
-        ca = ca[0]
     assert ca["flops"] < ana["flops"]
 
 
@@ -127,3 +126,22 @@ def test_cache_specs_long500k_unshardable_batch():
     # batch==1: replicate batch, shard seq over every axis
     assert all(s[1] is None for s in kv)
     assert all(s[2] == ("data", "model") for s in kv)
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """The persistent compile cache goes to $JAX_COMPILATION_CACHE_DIR when
+    that is set (JAX reads it itself), else to the checkout's fixed
+    ``.jax_cache`` — never a per-process path."""
+    from repro.launch import compile_cache as CC
+    root = Path(__file__).resolve().parents[1]
+    assert CC.DEFAULT_DIR == root / ".jax_cache"
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(CC.ENV_VAR, str(tmp_path / "env_cache"))
+    assert CC.enable_compile_cache() == str(tmp_path / "env_cache")
+    assert jax.config.jax_compilation_cache_dir == prev
+    monkeypatch.delenv(CC.ENV_VAR)
+    try:
+        assert CC.enable_compile_cache() == str(CC.DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CC.DEFAULT_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

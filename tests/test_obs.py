@@ -389,4 +389,5 @@ def test_jax_profile_context(tmp_path):
     from repro.obs.profile import jax_profile
     with jax_profile(tmp_path / "jaxtrace"):
         jax.block_until_ready(jax.numpy.ones((4,)) * 2)
-    # best-effort: no crash whether or not the profiler plugin exists
+    # the profiler really ran: its xplane dump is on disk
+    assert list((tmp_path / "jaxtrace").rglob("*.xplane.pb"))
