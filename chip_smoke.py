@@ -16,7 +16,7 @@ Phases (one process, no child processes; every failed check raises):
             ``sflv3_ac`` with the int8 transport (the ``cut_fuse``
             kernel), 2 epochs each as ONE dispatch per ``run``; prints
             compile seconds, steps, first/last loss, dispatches and the
-            device's peak bytes.
+            process's running peak of device bytes.
 * refs    — ``cut_fuse`` and ``dp_clip`` against their ``ref.py`` at the
             DenseNet cut shape; the compiled ``sflv3_ac`` epoch 0 against
             ``engine="stepwise"``; ``tpu_custom_call`` in the lowered text
@@ -26,7 +26,8 @@ Phases (one process, no child processes; every failed check raises):
 
 ``--four-chips`` runs only ``fl`` and ``sflv3_ac`` with ``shard=True``
 against ``shard=False`` (5 hospitals pad to 8, two per chip), at
-DenseNet-121's widths with its later dense blocks cut to one layer.
+DenseNet-121's widths with its later dense blocks cut to one layer, and
+``sflv3_ac`` on the identity link (the one-chip phases cover int8).
 
 The last stdout line is ``{"ok": true, "device": {...}}``.  Without a TPU
 (or with the Pallas kernels in interpret mode) the script says what it
@@ -49,6 +50,11 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 SEED = 0
 LR = 1e-3
 METHODS = ("fl", "sl_am", "sflv3_ac")
+PER_CLIENT = 16           # 2 batches of 8 per hospital per epoch
+BATCH = 8
+EPOCHS = 2
+BUCKETS = (1, 2, 4, 8)
+N_REQUESTS = 16
 # the chip's default f32 matmul/conv precision differs from the CPU's, and
 # the compiled and stepwise programs fuse differently: per-step losses of
 # the same math agree to this absolute tolerance (BCE losses are ~0.7)
@@ -56,26 +62,36 @@ TOL_STEPWISE = 5e-3
 TOL_SERVE = 1e-3          # |score| bucket program vs Strategy.scores
 TOL_DP_REL = 1e-5         # dp_clip kernel vs ref, relative to max |ref|
 TOL_QUANT_STEPS = 1.001   # int8 roundtrip: within one quantisation step
-# shard=True vs shard=False is the same math in another program too, so
-# its losses get TOL_STEPWISE.  Its params are compared by the size of
-# their difference relative to how far training moved them: Adam moves a
-# coordinate by ~lr per update whatever its gradient's size, so a
-# coordinate whose gradient sits at rounding level (or behind an int8
-# rounding flip) may move +lr in one run and -lr in the other, and an
-# absolute bound would be near lr * updates for any pair of runs.  The
-# int8 link amplifies it: one rounding flip at the cut is a whole
-# quantisation step (on the CPU a 1e-6 relative change of the init moves
-# sflv3_ac+int8 params 6.5e-3 of their movement, 7.9e-5 without int8).
-# A hospital trained on another's data moves its segment elsewhere, a
-# ratio near sqrt(2) there and ~1 overall at the four-chip depth, where
-# client and server segments are about the same size.
-TOL_SHARD_REL = 0.3
+# shard=True vs shard=False is the same math in another program, so its
+# losses get TOL_STEPWISE.  Its params are compared by the size of their
+# difference relative to how far training moved them,
+# ||θ_shard − θ_one|| / ||θ_one − θ_init||: Adam moves a coordinate by
+# ~lr per update whatever its gradient's size, so an absolute bound would
+# be near lr * updates for any pair of runs.  Rounding-level differences
+# stay far below this bound (on the CPU a 1e-6 relative change of the init
+# moves fl+DP params 2.1e-4 of their movement, sflv3_ac 7.9e-5), while a
+# shard=True run that drops the cross-chip server-gradient sum lands near
+# 1 (tests/test_chip_smoke.py plants that fault).  On a v5e, sflv3_ac
+# read 0.11 at the default matmul/conv precision (one bf16 pass for f32
+# operands) on the int8 link and on the identity link alike, and 3.4e-3
+# at f32 ("highest") precision, so its parity runs at f32 precision on the
+# identity link (the one-chip phases cover int8); fl reads 3.5e-3 at the
+# default precision.
+TOL_SHARD_REL = 1e-2
 # Four chips cost four times as much per second, and the full-depth
 # programs already compile and run in the one-chip phases.  The shard
 # parity runs keep DenseNet-121's widths, input size and client segment
 # (stem + 3 dense layers: the same cut shape and kernels) with one dense
 # layer in each later block.
 FOUR_CHIP_BLOCKS = (3, 1, 1, 1)
+# The [C, ...] arguments of each whole-run program, by position, with the
+# axis that carries the hospitals.  fl: batches, mask, example weights,
+# key grid.  sflv3_ac: client segments, their Adam state, batches, batch
+# index grid.
+HOSPITAL_ARGS = {"fl": {1: 1, 2: 0, 3: 0, 4: 1},
+                 "sflv3_ac": {0: 0, 2: 0, 4: 1, 5: 1}}
+# matmul/conv precision of each method's shard parity runs (None: default)
+SHARD_PRECISION = {"fl": None, "sflv3_ac": "highest"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,11 +101,6 @@ class Sizes:
     model: object = None
     image: int = 224
     n_clients: int = 5
-    per_client: int = 16      # 2 batches of 8 per hospital per epoch
-    batch: int = 8
-    epochs: int = 2
-    buckets: tuple = (1, 2, 4, 8)
-    n_requests: int = 16
 
     @property
     def cfg(self):
@@ -148,7 +159,8 @@ class CompileClock:
 
 
 def peak_bytes(device):
-    """``peak_bytes_in_use`` of a device; None where the backend keeps no
+    """``peak_bytes_in_use`` of a device: the process's running maximum,
+    never reset, so not one phase's peak.  None where the backend keeps no
     allocator stats (the CPU)."""
     stats = device.memory_stats()
     return None if stats is None else stats["peak_bytes_in_use"]
@@ -157,9 +169,9 @@ def peak_bytes(device):
 def make_clients(sizes: Sizes):
     from repro.data.synthetic import make_cxr_clients
     return make_cxr_clients(seed=SEED, n_clients=sizes.n_clients,
-                            train_per_client=sizes.per_client,
-                            val_per_client=sizes.batch,
-                            test_per_client=sizes.n_requests,
+                            train_per_client=PER_CLIENT,
+                            val_per_client=BATCH,
+                            test_per_client=N_REQUESTS,
                             image_size=sizes.image)
 
 
@@ -186,13 +198,13 @@ def train(method, clients, sizes: Sizes, clock, n_epochs=None, **kw):
     from repro.models.cnn import build_densenet
     strat = make_strategy(method, cnn_adapter(build_densenet(sizes.cfg)),
                           lambda: O.adam(LR), n_clients=sizes.n_clients,
-                          **method_options(method), **kw)
+                          **{**method_options(method), **kw})
     state = strat.setup(jax.random.key(SEED))
     c0, h0 = clock.seconds, clock.cache_hits
     t0 = time.perf_counter()
     state, logs = strat.run(state, [c.train for c in clients],
-                            np.random.default_rng(SEED), sizes.batch,
-                            n_epochs or sizes.epochs)
+                            np.random.default_rng(SEED), BATCH,
+                            n_epochs or EPOCHS)
     jax.block_until_ready(state)
     losses = np.concatenate([np.asarray(log.losses) for log in logs])
     check(losses.size and np.isfinite(losses).all(),
@@ -217,17 +229,16 @@ def train_phase(sizes: Sizes, clients, clock):
     """Each method's whole run, one dispatch each; returns the sflv3_ac
     strategy, its state and epoch logs for the later phases."""
     import jax
-    from repro.kernels import compat
     kept = None
     for method in METHODS:
         strat, state, logs, info = train(method, clients, sizes, clock)
         check(info["dispatches"] == 1,
               f"{method}: {info['dispatches']} dispatches for one run")
-        info["peak_bytes"] = peak_bytes(jax.devices()[0])
+        info["process_peak_bytes"] = peak_bytes(jax.devices()[0])
         if method != "sl_am":
             # the DP clip / int8 cut-layer kernels, not the interpreter
             n = custom_calls(strat)
-            check(compat.INTERPRET or n > 0,
+            check(n > 0,
                   f"{method}: no tpu_custom_call in the run program")
             info["tpu_custom_calls"] = n
         print(f"train {method}: " + " ".join(
@@ -253,7 +264,7 @@ def kernel_refs(sizes: Sizes):
 
     adapter = cnn_adapter(build_densenet(sizes.cfg))
     example = {"image": jax.ShapeDtypeStruct(
-        (sizes.batch, sizes.image, sizes.image, 1), jnp.float32)}
+        (BATCH, sizes.image, sizes.image, 1), jnp.float32)}
     cut = adapter.boundary_specs(example)["front->middle"]
     kx, kz, kg = jax.random.split(jax.random.key(SEED), 3)
     x = jax.random.normal(kx, cut.shape, jnp.float32)
@@ -274,11 +285,11 @@ def kernel_refs(sizes: Sizes):
     leaves = jax.tree.leaves(shapes)
     big = max(leaves, key=lambda l: l.size)
     small = min(leaves, key=lambda l: l.size)
-    scale = jnp.linspace(0.2, 3.0, sizes.batch).astype(jnp.float32)
+    scale = jnp.linspace(0.2, 3.0, BATCH).astype(jnp.float32)
     grads = {}
     for name, leaf in (("largest", big), ("smallest", small)):
         g = jax.random.normal(jax.random.fold_in(kg, leaf.size),
-                              (sizes.batch, *leaf.shape), jnp.float32)
+                              (BATCH, *leaf.shape), jnp.float32)
         grads[name] = g * (scale / np.sqrt(leaf.size)).reshape(
             (-1,) + (1,) * leaf.ndim)
     got, got_n = clip_accumulate(grads, 1.0)
@@ -309,24 +320,24 @@ def stepwise_ref(sizes: Sizes, clients, clock, compiled_logs):
           flush=True)
 
 
-def serve_phase(sizes: Sizes, clients, strat, state, clock):
+def serve_phase(clients, strat, state, clock):
     """Hospital 0's export behind the bucket ladder; single-image
     requests coalesce into buckets and never trigger a compile."""
     from repro.serving import ScreeningService
     test = clients[0].test
-    images = test["image"][:sizes.n_requests]
-    want = strat.scores(state, 0, {k: v[:sizes.n_requests]
+    images = test["image"][:N_REQUESTS]
+    want = strat.scores(state, 0, {k: v[:N_REQUESTS]
                                    for k, v in test.items()},
-                        batch_size=sizes.batch)
+                        batch_size=BATCH)
     servable = strat.export(state, client_idx=0)
     c0 = clock.seconds
-    with ScreeningService(servable, buckets=sizes.buckets,
+    with ScreeningService(servable, buckets=BUCKETS,
                           image_shape=images.shape[1:]) as svc:
         warm = svc.scorer.n_compiles
-        check(warm == len(sizes.buckets),
-              f"{warm} programs for {len(sizes.buckets)} buckets")
+        check(warm == len(BUCKETS),
+              f"{warm} programs for {len(BUCKETS)} buckets")
         compile_s = clock.seconds - c0
-        b = max(sizes.buckets)
+        b = max(BUCKETS)
         got8, info = svc.score_batch({"image": images[:b]})
         check(info["buckets"] == [b], f"bucket {info['buckets']} for {b}")
         d8 = float(np.max(np.abs(got8 - want[:b])))
@@ -341,7 +352,7 @@ def serve_phase(sizes: Sizes, clients, strat, state, clock):
     check(np.isfinite(served).all(), "non-finite served scores")
     check(d8 <= TOL_SERVE and dall <= TOL_SERVE,
           f"served scores off Strategy.scores: bucket {d8}, requests {dall}")
-    print(f"serve sflv3_ac hospital 0: buckets={sizes.buckets} "
+    print(f"serve sflv3_ac hospital 0: buckets={BUCKETS} "
           f"compile_s={compile_s} requests={len(reqs)} "
           f"batch_n_mean={stats['batch_n_mean']} "
           f"bucket{b}_max_abs_diff={d8} requests_max_abs_diff={dall} "
@@ -356,7 +367,7 @@ def one_chip(sizes: Sizes):
     strat, state, logs = train_phase(sizes, clients, clock)
     kernel_refs(sizes)
     stepwise_ref(sizes, clients, clock, logs)
-    serve_phase(sizes, clients, strat, state, clock)
+    serve_phase(clients, strat, state, clock)
 
 
 def drift(rows) -> float:
@@ -365,68 +376,109 @@ def drift(rows) -> float:
                          / sum(np.sum((a - c) ** 2) for _, a, _, c in rows)))
 
 
+def hospital_spread(method, strat, state) -> set:
+    """(devices, hospitals per device) of every [C, ...] array the last
+    run was called with and returned, read from the run's own recorded
+    invocation and state.  fl returns only the replicated global params,
+    which must span the same devices."""
+    import jax
+    _, args = strat._last_run_invocation
+    pairs = [(leaf, axis) for i, axis in HOSPITAL_ARGS[method].items()
+             for leaf in jax.tree.leaves(args[i])]
+    if method == "fl":
+        pairs += [(leaf, None) for leaf in jax.tree.leaves(state["params"])]
+    else:
+        pairs += [(leaf, 0) for leaf in jax.tree.leaves(
+            (state["stacked_clients"], state["c_opt"]))]
+    spread = set()
+    for leaf, axis in pairs:
+        if axis is not None and len(leaf.shape) <= axis:
+            continue                          # Adam's replicated step count
+        sh = leaf.sharding
+        if sh is None:
+            spread.add((1, None))             # never placed on the mesh
+        elif axis is None:
+            spread.add((len(sh.device_set),
+                        "replicated" if sh.is_fully_replicated else None))
+        else:
+            spread.add((len(sh.device_set), sh.shard_shape(leaf.shape)[axis]))
+    return spread
+
+
+def shard_parity(method, clients, sizes: Sizes, clock):
+    """One method with ``shard=True`` against ``shard=False``: losses,
+    params relative to their movement, and where the [C, ...] arrays
+    lived."""
+    import jax
+    from repro.wire import Transport
+    kw = {"transport": Transport("identity")} if method == "sflv3_ac" else {}
+    with jax.default_matmul_precision(SHARD_PRECISION[method]):
+        runs = {shard: train(method, clients, sizes, clock, shard=shard,
+                             **kw)
+                for shard in (False, True)}
+    (sa, xa, la, ia), (sb, xb, lb, ib) = runs[False], runs[True]
+    check(ia["dispatches"] == ib["dispatches"] == 1,
+          f"{method}: dispatches {ia['dispatches']}/{ib['dispatches']}")
+    dlosses = np.abs(np.concatenate([np.asarray(log.losses) for log in la])
+                     - np.concatenate([np.asarray(log.losses) for log in lb]))
+    dloss = float(np.max(dlosses))
+    x0 = sa.setup(jax.random.key(SEED))            # the runs' common init
+    rows = []            # (segment, shard=False, shard=True, init) leaves
+    for i in range(sizes.n_clients):
+        pa, pb, p0 = (s.params_for_eval(x, i)
+                      for s, x in ((sa, xa), (sb, xb), (sa, x0)))
+        for seg in pa:
+            rows += [(seg, *(np.asarray(v, np.float64) for v in t))
+                     for t in zip(*(jax.tree.leaves(p[seg])
+                                    for p in (pa, pb, p0)))]
+    dparam = max(float(np.max(np.abs(a - b))) for _, a, b, _ in rows)
+    rel = drift(rows)
+    segs = {g: drift([r for r in rows if r[0] == g]) for g in pa}
+    check(dloss <= TOL_STEPWISE and rel <= TOL_SHARD_REL,
+          f"{method}: shard=True off shard=False: loss {dloss}, "
+          f"params rel {rel} (max abs {dparam})")
+    place, n_dev = sb.placement, jax.device_count()
+    per_chip = place.c_pad // n_dev
+    check(place.enabled and place.c_pad % n_dev == 0,
+          f"{method}: placement {place}")
+    spread = hospital_spread(method, sb, xb)
+    want = {(n_dev, per_chip)} | ({(n_dev, "replicated")}
+                                  if method == "fl" else set())
+    check(spread == want, f"{method}: (devices, hospitals per device) of "
+          f"the run's arrays {spread}, want {want}")
+    # the server segment is replicated: every chip's copy must agree
+    replica_diff = max((float(np.max(np.abs(np.asarray(a.data)
+                                            - np.asarray(b.data))))
+                        for leaf in jax.tree.leaves(xb.get("server", {}))
+                        for a, b in zip(leaf.addressable_shards,
+                                        leaf.addressable_shards[1:])),
+                       default=None)
+    check(replica_diff in (None, 0.0),
+          f"{method}: the chips' server replicas differ by {replica_diff}")
+    print(f"four_chips {method} blocks={sizes.cfg.blocks}: "
+          f"link={kw['transport'].codec.name if kw else None} "
+          f"precision={SHARD_PRECISION[method]} "
+          "shard=True vs shard=False "
+          f"max_abs_loss_diff={dloss} (tol {TOL_STEPWISE}) "
+          f"hospital0_first_loss_diff={float(dlosses[0])} "
+          f"param_diff_over_movement={rel} (tol {TOL_SHARD_REL}) "
+          + "".join(f"{g}_over_movement={v} " for g, v in segs.items()) +
+          f"max_abs_param_diff={dparam} c_pad={place.c_pad} "
+          f"hospitals_per_chip={per_chip} device_set={n_dev} "
+          f"run_arrays={sorted(spread, key=str)} "
+          f"server_replica_max_abs_diff={replica_diff} "
+          f"compile_s={ia['compile_s']}/{ib['compile_s']} "
+          f"process_peak_bytes={[peak_bytes(d) for d in jax.devices()]}",
+          flush=True)
+
+
 def four_chips(sizes: Sizes):
     """fl and sflv3_ac with the hospital axis on a 4-device mesh against
     the same runs on one device: 5 hospitals pad to 8, two per chip."""
-    import jax
-    from repro.core.strategies import engine as ENG
     clock = CompileClock()
     clients = make_clients(sizes)
-    n_dev = jax.device_count()
     for method in ("fl", "sflv3_ac"):
-        runs = {shard: train(method, clients, sizes, clock, shard=shard)
-                for shard in (False, True)}
-        (sa, xa, la, ia), (sb, xb, lb, ib) = runs[False], runs[True]
-        check(ia["dispatches"] == ib["dispatches"] == 1,
-              f"{method}: dispatches {ia['dispatches']}/{ib['dispatches']}")
-        dloss = float(np.max(np.abs(
-            np.concatenate([np.asarray(log.losses) for log in la])
-            - np.concatenate([np.asarray(log.losses) for log in lb]))))
-        x0 = sa.setup(jax.random.key(SEED))        # the runs' common init
-        rows = []        # (segment, shard=False, shard=True, init) leaves
-        for i in range(sizes.n_clients):
-            pa, pb, p0 = (s.params_for_eval(x, i)
-                          for s, x in ((sa, xa), (sb, xb), (sa, x0)))
-            for seg in pa:
-                rows += [(seg, *(np.asarray(v, np.float64) for v in t))
-                         for t in zip(*(jax.tree.leaves(p[seg])
-                                        for p in (pa, pb, p0)))]
-        dparam = max(float(np.max(np.abs(a - b))) for _, a, b, _ in rows)
-        rel = drift(rows)
-        segs = {g: drift([r for r in rows if r[0] == g]) for g in pa}
-        check(dloss <= TOL_STEPWISE and rel <= TOL_SHARD_REL,
-              f"{method}: shard=True off shard=False: loss {dloss}, "
-              f"params rel {rel} (max abs {dparam})")
-        place = sb.placement
-        check(place.enabled and place.c_pad % n_dev == 0,
-              f"{method}: placement {place}")
-        # every [C, ...] array: the run's packed inputs, and the stacked
-        # client state the run returns
-        batches, packed = ENG.pack_run(
-            [c.train for c in clients], sizes.batch,
-            np.random.default_rng(SEED), sizes.epochs,
-            pad_clients=place.n_pad)
-        hosp = [place.put(batches, axis=1), place.put(packed.mask),
-                place.put(packed.ex_weights)]
-        if "stacked_clients" in xb:
-            hosp += [xb["stacked_clients"], xb["c_opt"]]
-        spans = set()
-        for leaf in jax.tree.leaves(hosp):
-            if getattr(leaf, "ndim", 0) == 0:
-                continue                      # Adam's replicated step count
-            spans.add(len(leaf.sharding.device_set))
-        check(spans == {n_dev}, f"{method}: [C,...] arrays span {spans} "
-              f"devices, want {n_dev}")
-        print(f"four_chips {method} blocks={sizes.cfg.blocks}: "
-              "shard=True vs shard=False "
-              f"max_abs_loss_diff={dloss} (tol {TOL_STEPWISE}) "
-              f"param_diff_over_movement={rel} (tol {TOL_SHARD_REL}) "
-              + "".join(f"{g}_over_movement={v} " for g, v in segs.items()) +
-              f"max_abs_param_diff={dparam} c_pad={place.c_pad} "
-              f"hospitals_per_chip={place.c_pad // n_dev} "
-              f"device_set={n_dev} compile_s={ia['compile_s']}/"
-              f"{ib['compile_s']} peak_bytes="
-              f"{[peak_bytes(d) for d in jax.devices()]}", flush=True)
+        shard_parity(method, clients, sizes, clock)
 
 
 def main(argv=None):

@@ -490,11 +490,18 @@ def abstract_args(args):
     What the strategies stash as ``_last_run_invocation``: ``jit.lower``
     accepts the abstract avals, so ``hlo_cost`` can re-lower the exact
     program without the stash pinning the run's donated (deleted) buffers
-    or the multi-epoch batch stack in memory.
+    or the multi-epoch batch stack in memory.  A committed array keeps its
+    sharding, so a ``shard=True`` run re-lowers as the sharded program and
+    the stash records where the run's inputs were placed.
     """
-    return jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(np.shape(a), jnp.asarray(a).dtype)
-        if not isinstance(a, jax.ShapeDtypeStruct) else a, args)
+    def abstract(a):
+        if isinstance(a, jax.ShapeDtypeStruct):
+            return a
+        sharding = a.sharding if getattr(a, "committed", False) else None
+        return jax.ShapeDtypeStruct(np.shape(a), jnp.asarray(a).dtype,
+                                    sharding=sharding)
+
+    return jax.tree.map(abstract, args)
 
 def empty_run(client_data, batch_size: int,
               drop_remainder: bool = True) -> bool:

@@ -10,10 +10,13 @@ The topology is described inside a module fixture — never at import —
 because only one process at a time may load the TPU library.
 """
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.act_compress.act_compress import (dequantize_pallas,
@@ -33,6 +36,8 @@ DP_BATCH = 8
 
 @pytest.fixture(scope="module")
 def topo():
+    # without it the TPU compiler writes its logs under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
         return topologies.get_topology_desc(platform="tpu",
@@ -47,8 +52,10 @@ def one_chip(topo):
     # persistent cache, so keep these compiles out of it
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
     yield SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
 
 
 def compile_text(fn, sharding, *avals):
