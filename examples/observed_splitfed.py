@@ -8,13 +8,14 @@ loss, grad/update norms, the FedAvg update cosine, cut-layer activation
 stats, DP clip fractions and the per-round RDP epsilon series ride the
 whole-run scan as extra outputs (params bit-identical to an unobserved
 run; tests/test_obs.py).  Around the dispatch, a ``Tracer`` records the
-host phases (pack -> dispatch), and the wire simulator replays each
-method's transfers over the hospital WAN into per-client timelines.
+host phases of the run (pack -> enqueue -> wait -> account, with their
+counters, and each compile), and the wire simulator replays each method's
+transfers over the hospital WAN into per-client timelines.
 
-All three views land in one ``trace_observed.json`` — engine-host lanes
-(with synthetic per-round slices carrying the telemetry and epsilon
-counter tracks), one simulated-wire lane per strategy — loadable in
-chrome://tracing or https://ui.perfetto.dev.  Per strategy it also writes
+Both views land in one ``trace_observed.json`` — one engine-host lane and
+one simulated-wire lane per strategy — loadable in chrome://tracing or
+https://ui.perfetto.dev; the per-round telemetry and epsilon series go to
+the run logs and reports.  Per strategy it also writes
 ``RUNLOG_<method>.json`` (telemetry + cost summary: dispatch count,
 compile seconds, HLO flop/byte estimates) and a markdown report.
 
@@ -36,8 +37,7 @@ from repro.core.strategies import make_strategy
 from repro.data.synthetic import make_cxr_clients
 from repro.models.cnn import DenseNetConfig, build_densenet
 from repro.obs import (Telemetry, Tracer, cost_summary, merge_events,
-                       round_events, wire_events, write_chrome_trace,
-                       write_runlog)
+                       wire_events, write_chrome_trace, write_runlog)
 from repro.obs.report import write_report
 from repro.privacy import PrivacyConfig
 from repro.wire import Transport
@@ -62,10 +62,8 @@ def observe_one(method, adapter, clients, batch, epochs, privacy):
     wall = time.perf_counter() - t0
     rt = strat.last_run_telemetry
 
-    # engine-host lane: real spans + synthetic per-round slices of the
-    # one dispatch, carrying telemetry args and epsilon counters
+    # engine-host lane: the run's spans, their counters and compiles
     events = tracer.trace_events()
-    events += round_events(rt, tracer.find("dispatch"))
 
     # wire lane: simulated per-client transfer timelines.  Cut-layer
     # methods replay the transport's REAL recorded accounting; FL (no cut
@@ -97,8 +95,7 @@ def main(argv=None):
     ap.add_argument("--epochs", type=int, default=None)
     ap.add_argument("--batch", type=int, default=None)
     ap.add_argument("--no-dp", action="store_true",
-                    help="train without DP-SGD (drops the epsilon "
-                         "counter tracks)")
+                    help="train without DP-SGD (no epsilon series)")
     args = ap.parse_args(argv)
 
     if args.smoke:

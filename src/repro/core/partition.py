@@ -11,6 +11,11 @@ architecture-agnostic.  Segments:
 
 Activations crossing segment boundaries may be arbitrary pytrees (the U-Net
 front emits (hidden, skips)); communication accounting sums leaf bytes.
+
+``full_loss`` runs each segment under ``jax.named_scope(<segment>)`` and
+the boundary hook under ``jax.named_scope("cut")``, so every operation of
+a compiled training program names the segment it belongs to
+(``repro.obs.scopes``); scopes are HLO metadata only.
 """
 
 from __future__ import annotations
@@ -51,9 +56,11 @@ class SplitAdapter:
         x = self.inputs(batch)
         last = len(self.seg_names) - 1
         for i, seg in enumerate(self.seg_names):
-            x = self.apply_seg(seg, params[seg], x, batch, train)
+            with jax.named_scope(seg):
+                x = self.apply_seg(seg, params[seg], x, batch, train)
             if boundary is not None and i < last:
-                x = boundary(x)
+                with jax.named_scope("cut"):
+                    x = boundary(x)
         if weights is None:
             return self.loss_from_output(x, batch)
         if self.per_example_loss is None:

@@ -27,6 +27,7 @@ round-off (asserted at 1e-5 in tests/test_engine.py).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 from typing import Callable
@@ -36,6 +37,8 @@ import numpy as np
 
 from repro.core.partition import SplitAdapter, stack_trees, unstack_tree
 from repro import optim as O
+# importing repro.obs installs its compile log before any program traces
+from repro.obs import compile_log
 
 
 @dataclasses.dataclass
@@ -65,6 +68,33 @@ class EpochLog:
         w = np.asarray(self.weights, dtype=np.float64)
         l = np.asarray(self.losses, dtype=np.float64)
         return float((l * w).sum() / max(w.sum(), 1.0))
+
+
+def _run_images(logs, batch_size: int) -> int:
+    """Training examples with nonzero weight over a run's epoch logs."""
+    n = 0
+    for log in logs:
+        if log.weights is not None:
+            n += int(np.sum(log.weights))
+        elif log.client_steps is not None:
+            n += int(sum(log.client_steps)) * batch_size
+        else:
+            n += int(log.steps) * batch_size
+    return n
+
+
+def _input_bytes(args) -> dict:
+    """The ``enqueue`` span's counters: bytes of every input array, and
+    of those still on the host (copied to the device by the call)."""
+    total = host = 0
+    for leaf in jax.tree.leaves(args):
+        if isinstance(leaf, np.ndarray):
+            host += leaf.nbytes
+            total += leaf.nbytes
+        elif isinstance(leaf, jax.Array) and not jax.dtypes.issubdtype(
+                leaf.dtype, jax.dtypes.prng_key):
+            total += leaf.nbytes
+    return {"bytes_in": int(total), "bytes_host": int(host)}
 
 
 # aggregation cores live in repro.core.aggregate (PR 9); re-exported here
@@ -217,29 +247,34 @@ class Strategy:
         prev = self._tel_active
         self._tel_active = tel
         try:
-            with self._span("run", strategy=self.name, n_epochs=n_epochs):
-                if self.engine == "compiled" and self._whole_run:
-                    out = self._run_compiled(state, client_data, rng,
-                                             batch_size, n_epochs)
-                    if out is not None:  # None: degenerate run, fall back
-                        state, logs = out
-                        return state, self._finish_run(client_data,
-                                                       batch_size, logs)
-                    if self.participation is not None:
-                        # the per-epoch fallback has no slot packing; a
-                        # degenerate participating run trains nothing
-                        return state, self._finish_run(client_data,
-                                                       batch_size, [])
-                logs = []
-                for i in range(n_epochs):
-                    with self._span(f"round {i}"):
-                        state, log = self.run_epoch(state, client_data,
-                                                    rng, batch_size)
-                    logs.append(log)
-                return state, self._finish_run(client_data, batch_size,
-                                               logs)
+            with self._span("run", strategy=self.name,
+                            n_epochs=n_epochs) as sp:
+                state, logs = self._run(state, client_data, rng,
+                                        batch_size, n_epochs)
+                logs = self._finish_run(client_data, batch_size, logs)
+                if sp is not None:
+                    sp.set(images=_run_images(logs, batch_size))
+                return state, logs
         finally:
             self._tel_active = prev
+
+    def _run(self, state, client_data, rng, batch_size, n_epochs):
+        if self.engine == "compiled" and self._whole_run:
+            out = self._run_compiled(state, client_data, rng, batch_size,
+                                     n_epochs)
+            if out is not None:  # None: degenerate run, fall back
+                return out
+            if self.participation is not None:
+                # the per-epoch fallback has no slot packing; a
+                # degenerate participating run trains nothing
+                return state, []
+        logs = []
+        for i in range(n_epochs):
+            with self._span(f"round {i}"):
+                state, log = self.run_epoch(state, client_data, rng,
+                                            batch_size)
+            logs.append(log)
+        return state, logs
 
     def _finish_run(self, client_data, batch_size, logs):
         """Assemble ``last_run_telemetry`` (one RoundTelemetry per epoch
@@ -286,16 +321,57 @@ class Strategy:
         return self._tel_active
 
     def attach_tracer(self, tracer):
-        """Attach a ``repro.obs.trace.Tracer`` — host-side phases (pack /
-        dispatch / collect / rounds) get recorded as spans."""
+        """Attach a ``repro.obs.trace.Tracer`` (``None`` detaches): each
+        run records ``run -> pack{gather, stack} -> enqueue -> wait ->
+        account`` spans with their counters, and every compile while it
+        is attached lands as a ``compile.*`` span."""
+        if self._tracer is not None:
+            compile_log.detach(self._tracer)
         self._tracer = tracer
+        if tracer is not None:
+            compile_log.attach(tracer)
         return tracer
 
     def _span(self, name, **args):
+        """The tracer's span (yields a ``Span`` for counters), or a no-op
+        context yielding None when no tracer is attached."""
         if self._tracer is None:
-            import contextlib
             return contextlib.nullcontext()
         return self._tracer.span(name, **args)
+
+    @staticmethod
+    def _pack_span(sp, batches: dict, slots: int, real: int):
+        """The ``pack`` span's counters, when traced: bytes packed per
+        data key, batch slots packed and the real (non-padding) batches
+        among them."""
+        if sp is not None:
+            sp.set(**{f"bytes_{k}": int(v.nbytes) for k, v in batches.items()},
+                   batch_slots=int(slots), real_batches=int(real))
+
+    def _enqueue(self, fn, args, stash: bool = True):
+        """Call a compiled program under the ``enqueue`` span: the jitted
+        call, which copies host inputs to the device and returns once the
+        program is queued.  ``stash`` keeps ``(fn, abstract args)`` as
+        ``_last_run_invocation`` (``obs.profile.hlo_cost``) and on the
+        span, so the program's device operations can be named."""
+        with self._span("enqueue") as sp:
+            if sp is not None:
+                sp.set(program=getattr(fn, "__name__", "program"),
+                       **_input_bytes(args))
+            out = fn(*args)
+            if stash:
+                from repro.core.strategies.engine import abstract_args
+                self._last_run_invocation = (fn, abstract_args(args))
+                if sp is not None:
+                    sp.program = self._last_run_invocation
+        self._count_dispatch()
+        return out
+
+    def _wait(self, losses) -> np.ndarray:
+        """The run's losses on the host, under the ``wait`` span: the host
+        is blocked until the program has finished."""
+        with self._span("wait"):
+            return np.asarray(losses)
 
     def _count_dispatch(self, n: int = 1):
         """Tally one host->device training-program invocation (a compiled
@@ -546,6 +622,14 @@ class Strategy:
 # and the compiled engine's scan bodies (repro.core.strategies.engine)
 # ---------------------------------------------------------------------------
 
+def _apply_update(opt: O.Optimizer, grads, opt_state, params):
+    """One optimizer step under the ``update`` scope (``repro.obs.scopes``):
+    returns ``(new params, new optimizer state, updates)``."""
+    with jax.named_scope("update"):
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return O.apply_updates(params, updates), opt_state, updates
+
+
 def full_step_fn(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
                  telemetry=None):
     """Pure step over ALL segments jointly (centralized / FL local).
@@ -575,7 +659,8 @@ def full_step_fn(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
                             weights=None):
                 out = vg(params, batch, key, weights)
                 loss, grads = out[0], out[1]
-                updates, opt_state = opt.update(grads, opt_state, params)
+                params, opt_state, updates = _apply_update(
+                    opt, grads, opt_state, params)
                 met = {}
                 if "grad_norm" in keys:
                     met["grad_norm"] = T.global_norm(grads)
@@ -583,8 +668,7 @@ def full_step_fn(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
                 if "clip_frac" in keys:
                     met["clip_frac"] = T.clip_fraction(
                         out[2]["norms"], privacy.clip_norm, weights)
-                return (O.apply_updates(params, updates), opt_state, loss,
-                        met)
+                return params, opt_state, loss, met
             return dp_step_obs, True
 
         vg = dp_value_and_grad(keyed(adapter.full_loss), privacy)
@@ -594,8 +678,9 @@ def full_step_fn(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
             # zero-weight padded examples clip to zero contribution and the
             # 1/B mean divides by the REAL example count
             loss, grads = vg(params, batch, key, weights)
-            updates, opt_state = opt.update(grads, opt_state, params)
-            return O.apply_updates(params, updates), opt_state, loss
+            params, opt_state, _ = _apply_update(opt, grads, opt_state,
+                                                 params)
+            return params, opt_state, loss
         return dp_step, True
 
     if telemetry is not None:
@@ -606,19 +691,20 @@ def full_step_fn(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
             loss, grads = jax.value_and_grad(
                 lambda p: adapter.full_loss(p, batch,
                                             weights=weights))(params)
-            updates, opt_state = opt.update(grads, opt_state, params)
+            params, opt_state, updates = _apply_update(opt, grads,
+                                                       opt_state, params)
             met = {}
             if "grad_norm" in keys:
                 met["grad_norm"] = T.global_norm(grads)
                 met["update_norm"] = T.global_norm(updates)
-            return O.apply_updates(params, updates), opt_state, loss, met
+            return params, opt_state, loss, met
         return step_obs, False
 
     def step(params, opt_state, batch, key=None, weights=None):
         loss, grads = jax.value_and_grad(
             lambda p: adapter.full_loss(p, batch, weights=weights))(params)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        return O.apply_updates(params, updates), opt_state, loss
+        params, opt_state, _ = _apply_update(opt, grads, opt_state, params)
+        return params, opt_state, loss
     return step, False
 
 
@@ -721,14 +807,15 @@ def split_step_fn(adapter: SplitAdapter, opt_client: O.Optimizer,
                     else:
                         loss, g = jax.value_and_grad(loss_fn)(both0, batch,
                                                               key)
-                cu, c_opt = opt_client.update(g["c"], c_opt, client_params)
-                su, s_opt = opt_server.update(g["s"], s_opt, server_params)
+                client_params, c_opt, cu = _apply_update(
+                    opt_client, g["c"], c_opt, client_params)
+                server_params, s_opt, su = _apply_update(
+                    opt_server, g["s"], s_opt, server_params)
                 if want_norms:
                     met["grad_norm"] = T.global_norm(g)
                     met["update_norm"] = T.global_norm((cu, su))
-                return (O.apply_updates(client_params, cu),
-                        O.apply_updates(server_params, su), c_opt, s_opt,
-                        loss, met)
+                return (client_params, server_params, c_opt, s_opt, loss,
+                        met)
             return dp_step_obs, True
 
         def dp_step(client_params, server_params, c_opt, s_opt, batch,
@@ -754,10 +841,11 @@ def split_step_fn(adapter: SplitAdapter, opt_client: O.Optimizer,
             else:
                 loss, g = jax.value_and_grad(loss_fn)(
                     {"c": client_params, "s": server_params}, batch, key)
-            cu, c_opt = opt_client.update(g["c"], c_opt, client_params)
-            su, s_opt = opt_server.update(g["s"], s_opt, server_params)
-            return (O.apply_updates(client_params, cu),
-                    O.apply_updates(server_params, su), c_opt, s_opt, loss)
+            client_params, c_opt, _ = _apply_update(opt_client, g["c"],
+                                                    c_opt, client_params)
+            server_params, s_opt, _ = _apply_update(opt_server, g["s"],
+                                                    s_opt, server_params)
+            return client_params, server_params, c_opt, s_opt, loss
         return dp_step, True
 
     if telemetry is not None:
@@ -783,16 +871,17 @@ def split_step_fn(adapter: SplitAdapter, opt_client: O.Optimizer,
             else:
                 loss, (gc, gs) = jax.value_and_grad(
                     loss_fn, argnums=(0, 1))(client_params, server_params)
-            cu, c_opt = opt_client.update(gc, c_opt, client_params)
-            su, s_opt = opt_server.update(gs, s_opt, server_params)
+            client_params, c_opt, cu = _apply_update(opt_client, gc, c_opt,
+                                                      client_params)
+            server_params, s_opt, su = _apply_update(opt_server, gs, s_opt,
+                                                     server_params)
             met = {}
             if want_cut:
                 met.update(T.moments_to_stats(*mom))
             if want_norms:
                 met["grad_norm"] = T.global_norm((gc, gs))
                 met["update_norm"] = T.global_norm((cu, su))
-            return (O.apply_updates(client_params, cu),
-                    O.apply_updates(server_params, su), c_opt, s_opt, loss,
+            return (client_params, server_params, c_opt, s_opt, loss,
                     met)
         return step_obs, False
 
@@ -807,10 +896,11 @@ def split_step_fn(adapter: SplitAdapter, opt_client: O.Optimizer,
 
         loss, (gc, gs) = jax.value_and_grad(loss_fn, argnums=(0, 1))(
             client_params, server_params)
-        cu, c_opt = opt_client.update(gc, c_opt, client_params)
-        su, s_opt = opt_server.update(gs, s_opt, server_params)
-        return (O.apply_updates(client_params, cu),
-                O.apply_updates(server_params, su), c_opt, s_opt, loss)
+        client_params, c_opt, _ = _apply_update(opt_client, gc, c_opt,
+                                                client_params)
+        server_params, s_opt, _ = _apply_update(opt_server, gs, s_opt,
+                                                server_params)
+        return client_params, server_params, c_opt, s_opt, loss
     return step, False
 
 
@@ -878,6 +968,13 @@ def sflv3_step_fn(adapter: SplitAdapter, opt_client: O.Optimizer,
             return gs_local
         return jax.lax.psum(gs_local, mesh_axis)
 
+    def _weighted_server_mean(g_server, w_local):
+        """DP path: the weighted mean of per-client server gradients."""
+        with jax.named_scope("update"):
+            return _server_mean(jax.tree.map(
+                lambda x: (x * w_local.reshape((-1,) + (1,) * (x.ndim - 1))
+                           ).sum(axis=0) / w_sum, g_server))
+
     if priv is not None:
         from repro.privacy.dpsgd import boundary_with_key, dp_value_and_grad
 
@@ -936,18 +1033,16 @@ def sflv3_step_fn(adapter: SplitAdapter, opt_client: O.Optimizer,
                 losses, g, met = jax.vmap(one)(stacked_clients,
                                                stacked_batch, keys)
                 gc = g["c"]                      # already per-client grads
-                gs = _server_mean(jax.tree.map(
-                    lambda x: (x * w_local.reshape(
-                        (-1,) + (1,) * (x.ndim - 1))).sum(axis=0) / w_sum,
-                    g["s"]))
-                cu, c_opt = opt_client.update(gc, c_opt, stacked_clients)
-                su, s_opt = opt_server.update(gs, s_opt, server_params)
+                gs = _weighted_server_mean(g["s"], w_local)
+                stacked_clients, c_opt, cu = _apply_update(
+                    opt_client, gc, c_opt, stacked_clients)
+                server_params, s_opt, su = _apply_update(
+                    opt_server, gs, s_opt, server_params)
                 if want_norms:
                     met["update_norm"] = jnp.sqrt(
                         jax.vmap(lambda u: jnp.square(T.global_norm(u)))(cu)
                         + jnp.square(T.global_norm(su)))
-                return (O.apply_updates(stacked_clients, cu),
-                        O.apply_updates(server_params, su), c_opt, s_opt,
+                return (stacked_clients, server_params, c_opt, s_opt,
                         losses, met)
             return dp_step_obs, True
 
@@ -974,14 +1069,12 @@ def sflv3_step_fn(adapter: SplitAdapter, opt_client: O.Optimizer,
 
             losses, g = jax.vmap(one)(stacked_clients, stacked_batch, keys)
             gc = g["c"]                          # already per-client grads
-            gs = _server_mean(jax.tree.map(
-                lambda x: (x * w_local.reshape((-1,) + (1,) * (x.ndim - 1))
-                           ).sum(axis=0) / w_sum, g["s"]))
-            cu, c_opt = opt_client.update(gc, c_opt, stacked_clients)
-            su, s_opt = opt_server.update(gs, s_opt, server_params)
-            return (O.apply_updates(stacked_clients, cu),
-                    O.apply_updates(server_params, su), c_opt, s_opt,
-                    losses)
+            gs = _weighted_server_mean(g["s"], w_local)
+            stacked_clients, c_opt, _ = _apply_update(
+                opt_client, gc, c_opt, stacked_clients)
+            server_params, s_opt, _ = _apply_update(opt_server, gs, s_opt,
+                                                    server_params)
+            return (stacked_clients, server_params, c_opt, s_opt, losses)
         return dp_step, True
 
     if telemetry is not None:
@@ -1009,10 +1102,13 @@ def sflv3_step_fn(adapter: SplitAdapter, opt_client: O.Optimizer,
             (_, (losses, moms)), (gc, gs) = jax.value_and_grad(
                 mean_loss, argnums=(0, 1), has_aux=True)(stacked_clients,
                                                          server_params)
-            gc = jax.tree.map(lambda g: g * w_sum, gc)
-            gs = _server_mean(gs)
-            cu, c_opt = opt_client.update(gc, c_opt, stacked_clients)
-            su, s_opt = opt_server.update(gs, s_opt, server_params)
+            with jax.named_scope("update"):
+                gc = jax.tree.map(lambda g: g * w_sum, gc)
+                gs = _server_mean(gs)
+            new_sc, c_opt, cu = _apply_update(opt_client, gc, c_opt,
+                                              stacked_clients)
+            new_sp, s_opt, su = _apply_update(opt_server, gs, s_opt,
+                                              server_params)
             met = {}
             if want_cut:
                 met.update(T.moments_to_stats(*moms))
@@ -1025,9 +1121,7 @@ def sflv3_step_fn(adapter: SplitAdapter, opt_client: O.Optimizer,
                 met["update_norm"] = jnp.sqrt(
                     jax.vmap(lambda u: jnp.square(T.global_norm(u)))(cu)
                     + jnp.square(T.global_norm(su)))
-            return (O.apply_updates(stacked_clients, cu),
-                    O.apply_updates(server_params, su), c_opt, s_opt,
-                    losses, met)
+            return new_sc, new_sp, c_opt, s_opt, losses, met
         return step_obs, False
 
     def step(stacked_clients, server_params, c_opt, s_opt, stacked_batch,
@@ -1052,12 +1146,14 @@ def sflv3_step_fn(adapter: SplitAdapter, opt_client: O.Optimizer,
         # gc is stacked per-client (weighted-mean grad => scale back to
         # per-client; a zero-weight phantom row's grad is exactly zero, so
         # the uniform w_sum rescale leaves it zero)
-        gc = jax.tree.map(lambda g: g * w_sum, gc)
-        gs = _server_mean(gs)
-        cu, c_opt = opt_client.update(gc, c_opt, stacked_clients)
-        su, s_opt = opt_server.update(gs, s_opt, server_params)
-        return (O.apply_updates(stacked_clients, cu),
-                O.apply_updates(server_params, su), c_opt, s_opt, losses)
+        with jax.named_scope("update"):
+            gc = jax.tree.map(lambda g: g * w_sum, gc)
+            gs = _server_mean(gs)
+        stacked_clients, c_opt, _ = _apply_update(opt_client, gc, c_opt,
+                                                  stacked_clients)
+        server_params, s_opt, _ = _apply_update(opt_server, gs, s_opt,
+                                                server_params)
+        return stacked_clients, server_params, c_opt, s_opt, losses
     return step, False
 
 

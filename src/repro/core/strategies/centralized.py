@@ -75,9 +75,11 @@ class Centralized(Strategy):
     def _run_epoch_compiled(self, state, pooled, rng, batch_size):
         from repro.core.strategies import engine as ENG
         tel = self._tel
-        with self._span("pack"):
+        with self._span("pack") as sp:
             packed = ENG.pack_epoch([pooled], batch_size, rng,
-                                    self.drop_remainder)
+                                    self.drop_remainder, span=self._span)
+            self._pack_span(sp, packed.batches, packed.mask.size,
+                            sum(packed.n_batches))
         nb = packed.n_batches[0]
         if nb == 0:
             return state, EpochLog([], 0)
@@ -96,20 +98,21 @@ class Centralized(Strategy):
             key_idx[:nb] = self._take_key_indices(nb)
         batches = {k: v[0] for k, v in packed.batches.items()}
         ex_w = None if packed.ex_weights is None else packed.ex_weights[0]
-        with self._span("dispatch"):
-            out = epoch_fn(
-                state["params"], state["opt"], batches, packed.mask[0],
-                ex_w, key_idx, self._privacy_base_key())
-        self._count_dispatch()
-        state["params"], state["opt"], losses = out[0], out[1], out[2]
-        flat = [float(x) for x in np.asarray(losses)[:nb]]
-        for ci in range(self.n_clients):
-            self._dp_account(ci, packed.n_samples[0], batch_size, count=nb)
-        log = EpochLog(flat, nb, weights=packed.step_examples[0])
-        if tel is not None:
-            log.telemetry = self._round_telemetry(
-                tel, flat,
-                {k: np.asarray(v)[:nb] for k, v in out[3].items()})
+        out = self._enqueue(epoch_fn, (
+            state["params"], state["opt"], batches, packed.mask[0], ex_w,
+            key_idx, self._privacy_base_key()), stash=False)
+        state["params"], state["opt"] = out[0], out[1]
+        losses = self._wait(out[2])
+        with self._span("account"):
+            flat = [float(x) for x in losses[:nb]]
+            for ci in range(self.n_clients):
+                self._dp_account(ci, packed.n_samples[0], batch_size,
+                                 count=nb)
+            log = EpochLog(flat, nb, weights=packed.step_examples[0])
+            if tel is not None:
+                log.telemetry = self._round_telemetry(
+                    tel, flat,
+                    {k: np.asarray(v)[:nb] for k, v in out[3].items()})
         return state, log
 
     @property
@@ -118,14 +121,17 @@ class Centralized(Strategy):
 
     def _run_compiled(self, state, client_data, rng, batch_size, n_epochs):
         from repro.core.strategies import engine as ENG
-        pooled = {k: np.concatenate([d[k] for d in client_data])
-                  for k in client_data[0]}
-        if ENG.empty_run([pooled], batch_size, self.drop_remainder):
-            return None
         tel = self._tel
-        with self._span("pack"):
+        with self._span("pack") as sp:
+            pooled = {k: np.concatenate([d[k] for d in client_data])
+                      for k in client_data[0]}
+            if ENG.empty_run([pooled], batch_size, self.drop_remainder):
+                return None
             batches, packed = ENG.pack_run([pooled], batch_size, rng,
-                                           n_epochs, self.drop_remainder)
+                                           n_epochs, self.drop_remainder,
+                                           span=self._span)
+            self._pack_span(sp, batches, n_epochs * packed.mask.size,
+                            n_epochs * sum(packed.n_batches))
         nb = packed.n_batches[0]
         if tel is None:
             if not hasattr(self, "_run_c"):
@@ -145,25 +151,26 @@ class Centralized(Strategy):
         ex_w = None if packed.ex_weights is None else packed.ex_weights[0]
         args = (state["params"], state["opt"], batches, packed.mask[0],
                 ex_w, key_idx, self._privacy_base_key())
-        with self._span("dispatch"):
-            out = run_fn(*args)
-        self._count_dispatch()
-        self._last_run_invocation = (run_fn, ENG.abstract_args(args))
-        state["params"], state["opt"], losses = out[0], out[1], out[2]
+        out = self._enqueue(run_fn, args)
+        state["params"], state["opt"] = out[0], out[1]
         self._run_calls = getattr(self, "_run_calls", 0) + 1
-        losses = np.asarray(losses)
-        logs = [EpochLog([float(x) for x in losses[e, :nb]], nb,
-                         weights=packed.step_examples[0])
-                for e in range(n_epochs)]
-        if tel is not None:
-            met = {k: np.asarray(v) for k, v in out[3].items()}
-            for e, log in enumerate(logs):
-                log.telemetry = self._round_telemetry(
-                    tel, [float(x) for x in losses[e, :nb]],
-                    {k: v[e, :nb] for k, v in met.items()})
-        for ci in range(self.n_clients):
-            self._dp_account(ci, packed.n_samples[0], batch_size,
-                             count=nb * n_epochs)
+        losses = self._wait(out[2])
+        with self._span("account"):
+            logs = [EpochLog([float(x) for x in losses[e, :nb]], nb,
+                             weights=packed.step_examples[0])
+                    for e in range(n_epochs)]
+            if tel is not None:
+                met = {k: np.asarray(v) for k, v in out[3].items()}
+                for e, log in enumerate(logs):
+                    log.telemetry = self._round_telemetry(
+                        tel, [float(x) for x in losses[e, :nb]],
+                        {k: v[e, :nb] for k, v in met.items()})
+            for ci in range(self.n_clients):
+                self._dp_account(ci, packed.n_samples[0], batch_size,
+                                 count=nb * n_epochs)
+            # the run's host batches and donated inputs are freed here,
+            # inside "account", not in the frame's teardown after it
+            del args, batches, packed, pooled, out
         return state, logs
 
     def params_for_eval(self, state, client_idx):

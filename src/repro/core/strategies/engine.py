@@ -52,6 +52,7 @@ multi-device host.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import warnings
@@ -109,10 +110,14 @@ def _client_batch_count(n: int, batch_size: int,
     return nb_full + (1 if rem and not drop_remainder else 0), nb_full, rem
 
 
+def _no_span(name, **args):
+    return contextlib.nullcontext()
+
+
 def pack_epoch(client_data: list, batch_size: int,
                rng: np.random.Generator | None,
                drop_remainder: bool = True,
-               pad_clients: int = 0) -> PackedEpoch:
+               pad_clients: int = 0, span=_no_span) -> PackedEpoch:
     """Shuffle + pack every hospital's epoch (mirrors ``np_batches``).
 
     The per-client shuffles consume ``rng`` in hospital order — exactly the
@@ -123,7 +128,15 @@ def pack_epoch(client_data: list, batch_size: int,
     zero batches, all-False mask rows) so the hospital axis reaches a
     device multiple for ``core.placement`` — phantom rows are masked
     no-ops in every scan and carry zero weight in every aggregation.
+
+    ``span`` (``Strategy._span``) times the shuffle and copy as ``gather``.
     """
+    with span("gather"):
+        return _pack_epoch(client_data, batch_size, rng, drop_remainder,
+                           pad_clients)
+
+
+def _pack_epoch(client_data, batch_size, rng, drop_remainder, pad_clients):
     n_batches, n_samples, step_examples, order = [], [], [], []
     for d in client_data:
         n = len(next(iter(d.values())))
@@ -517,7 +530,8 @@ def empty_run(client_data, batch_size: int,
 
 
 def pack_run(client_data, batch_size: int, rng, n_epochs: int,
-             drop_remainder: bool = True, pad_clients: int = 0):
+             drop_remainder: bool = True, pad_clients: int = 0,
+             span=_no_span):
     """Pack ``n_epochs`` epochs into ``[n_epochs, n_clients, nb_max, ...]``.
 
     Consumes ``rng`` exactly as a stepwise loop of per-epoch packs would
@@ -528,13 +542,15 @@ def pack_run(client_data, batch_size: int, rng, n_epochs: int,
     first epoch's.  ``pad_clients`` phantom hospitals (see ``pack_epoch``)
     ride along on axis 1.  Memory grows linearly with ``n_epochs`` (the
     whole run's batch grid lives in one buffer); callers with huge runs
-    can chunk ``run`` into several calls.
+    can chunk ``run`` into several calls.  ``span`` times each epoch's
+    ``gather`` (``pack_epoch``) and the ``stack`` into one buffer.
     """
     packs = [pack_epoch(client_data, batch_size, rng, drop_remainder,
-                        pad_clients)
+                        pad_clients, span)
              for _ in range(n_epochs)]
-    batches = {k: np.stack([p.batches[k] for p in packs])
-               for k in packs[0].batches}
+    with span("stack"):
+        batches = {k: np.stack([p.batches[k] for p in packs])
+                   for k in packs[0].batches}
     return batches, packs[0]
 
 
@@ -566,13 +582,15 @@ def make_fl_run(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
     observed = telemetry is not None
     want_cos = observed and telemetry.update_cosine
 
-    def run(global_params, batches, mask, ex_w, key_idx, base_key, agg_w):
-        if aggregator is None:
-            w = agg_w.astype(jnp.float32) / agg_w.astype(jnp.float32).sum()
-            reduce = lambda stacked, gp: _weighted_mean(stacked, w)
-        else:
-            reduce = lambda stacked, gp: aggregator.aggregate(
-                stacked, agg_w, gp)
+    def fl_run(global_params, batches, mask, ex_w, key_idx, base_key,
+               agg_w):
+        w = agg_w.astype(jnp.float32) / agg_w.astype(jnp.float32).sum()
+
+        def reduce(stacked, gp):
+            with jax.named_scope("update"):
+                if aggregator is None:
+                    return _weighted_mean(stacked, w)
+                return aggregator.aggregate(stacked, agg_w, gp)
 
         def round_body(gp, xs):
             b_e, ki_e = xs
@@ -591,7 +609,7 @@ def make_fl_run(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
         return jax.lax.scan(round_body, global_params, (batches, key_idx))
 
     # donate the param carry (aliased into the output) + the batch stack
-    return _donating_jit(run, donate_argnums=(0, 1))
+    return _donating_jit(fl_run, donate_argnums=(0, 1))
 
 
 def make_seq_run(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
@@ -605,7 +623,7 @@ def make_seq_run(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
     epoch = _seq_epoch_body(adapter, opt, privacy, telemetry)
     observed = telemetry is not None
 
-    def run(params, opt_state, batches, mask, ex_w, key_idx, base_key):
+    def seq_run(params, opt_state, batches, mask, ex_w, key_idx, base_key):
         def round_body(carry, xs):
             b_e, ki_e = xs
             out = epoch(*carry, b_e, mask, ex_w, ki_e, base_key)
@@ -618,7 +636,7 @@ def make_seq_run(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
             return (params, opt_state, *ys)
         return params, opt_state, ys
 
-    return _donating_jit(run, donate_argnums=(0, 1, 2))
+    return _donating_jit(seq_run, donate_argnums=(0, 1, 2))
 
 
 def make_interleaved_run(adapter: SplitAdapter, opt_client: O.Optimizer,
@@ -641,15 +659,16 @@ def make_interleaved_run(adapter: SplitAdapter, opt_client: O.Optimizer,
     sync_w = (None if client_weights is None
               else jnp.asarray(client_weights, jnp.float32))
 
-    def run(stacked_clients, server, stacked_c_opts, s_opt, batches, ex_w,
-            sched, key_idx, base_key):
+    def interleaved_run(stacked_clients, server, stacked_c_opts, s_opt,
+                        batches, ex_w, sched, key_idx, base_key):
         def round_body(carry, xs):
             b_e, ki_e = xs
             out = epoch(*carry, b_e, ex_w, sched, ki_e, base_key)
             sc, sp, co, so = out[0], out[1], out[2], out[3]
             ys = (out[4], out[5]) if observed else out[4]
             if sync_clients:
-                sc = _mean_sync(sc, sync_w)
+                with jax.named_scope("update"):
+                    sc = _mean_sync(sc, sync_w)
             return (sc, sp, co, so), ys
 
         carry, ys = jax.lax.scan(
@@ -657,7 +676,7 @@ def make_interleaved_run(adapter: SplitAdapter, opt_client: O.Optimizer,
             (batches, key_idx))
         return (*carry, *ys) if observed else (*carry, ys)
 
-    return _donating_jit(run, donate_argnums=(0, 1, 2, 3, 4))
+    return _donating_jit(interleaved_run, donate_argnums=(0, 1, 2, 3, 4))
 
 
 def make_sflv3_run(adapter: SplitAdapter, opt_client: O.Optimizer,
@@ -679,15 +698,16 @@ def make_sflv3_run(adapter: SplitAdapter, opt_client: O.Optimizer,
     sync_w = (None if client_weights is None
               else jnp.asarray(client_weights, jnp.float32))
 
-    def run(stacked_clients, server, c_opt, s_opt, batches, b_idx, key_idx,
-            base_key):
+    def sflv3_run(stacked_clients, server, c_opt, s_opt, batches, b_idx,
+                  key_idx, base_key):
         def round_body(carry, xs):
             b_e, ki_e = xs
             out = epoch(*carry, b_e, b_idx, ki_e, base_key)
             sc, sp, co, so = out[0], out[1], out[2], out[3]
             ys = (out[4], out[5]) if observed else out[4]
             if sync_clients:
-                sc = _mean_sync(sc, sync_w)
+                with jax.named_scope("update"):
+                    sc = _mean_sync(sc, sync_w)
             return (sc, sp, co, so), ys
 
         carry, ys = jax.lax.scan(
@@ -695,7 +715,7 @@ def make_sflv3_run(adapter: SplitAdapter, opt_client: O.Optimizer,
             (batches, key_idx))
         return (*carry, *ys) if observed else (*carry, ys)
 
-    return _donating_jit(run, donate_argnums=(0, 1, 2, 3, 4))
+    return _donating_jit(sflv3_run, donate_argnums=(0, 1, 2, 3, 4))
 
 
 # ---------------------------------------------------------------------------
@@ -747,7 +767,7 @@ class ParticipationPack:
 
 def pack_participation_run(client_data, batch_size: int, rng,
                            n_epochs: int, participation,
-                           drop_remainder: bool = True):
+                           drop_remainder: bool = True, span=_no_span):
     """Pack ``n_epochs`` participating rounds into
     ``[n_epochs, n_slots, nb_max, batch, ...]`` batch stacks.
 
@@ -758,8 +778,17 @@ def pack_participation_run(client_data, batch_size: int, rng,
     hospital), never on who else was sampled (co-sample independence),
     and ``Participation(k=N)`` packs arrays bit-identical to
     ``pack_run``'s.  ``nb_max`` is the max batch count over ALL N
-    hospitals, so the slot grid never reshapes across rounds.
+    hospitals, so the slot grid never reshapes across rounds.  ``span``
+    times the shuffles and copies straight into the grid as ``gather``.
     """
+    with span("gather"):
+        return _pack_participation_run(client_data, batch_size, rng,
+                                       n_epochs, participation,
+                                       drop_remainder)
+
+
+def _pack_participation_run(client_data, batch_size, rng, n_epochs,
+                            participation, drop_remainder):
     N = len(client_data)
     if participation.n_global != N:
         raise ValueError(f"participation.n_global={participation.n_global} "
@@ -837,29 +866,47 @@ def make_fl_run_participation(adapter: SplitAdapter, opt: O.Optimizer,
     observed = telemetry is not None
     want_cos = observed and telemetry.update_cosine
 
-    def run(global_params, batches, mask, ex_w, key_idx, base_key, agg_w,
-            staleness, slot_gid):
+    def fl_run_participation(global_params, batches, mask, ex_w, key_idx,
+                             base_key, agg_w, staleness, slot_gid):
         def round_body(gp, xs):
             b_e, m_e, w_e, ki_e, aw_e, st_e, gid_e = xs
             if observed:
                 stacked, losses, met = epoch(gp, b_e, m_e, w_e, ki_e,
                                              base_key)
+            else:
+                stacked, losses = epoch(gp, b_e, m_e, w_e, ki_e, base_key)
+            with jax.named_scope("update"):
                 new_gp = aggregator.aggregate(stacked, aw_e, gp, st_e,
                                               gid_e)
-                if want_cos:
-                    met = dict(met)
-                    met["update_cosine"] = _update_cosine(stacked, gp,
-                                                          new_gp)
-                return new_gp, (losses, met)
-            stacked, losses = epoch(gp, b_e, m_e, w_e, ki_e, base_key)
-            return aggregator.aggregate(stacked, aw_e, gp, st_e,
-                                        gid_e), losses
+            if not observed:
+                return new_gp, losses
+            if want_cos:
+                met = dict(met)
+                met["update_cosine"] = _update_cosine(stacked, gp, new_gp)
+            return new_gp, (losses, met)
 
         return jax.lax.scan(
             round_body, global_params,
             (batches, mask, ex_w, key_idx, agg_w, staleness, slot_gid))
 
-    return _donating_jit(run, donate_argnums=(0, 1))
+    return _donating_jit(fl_run_participation, donate_argnums=(0, 1))
+
+
+def _slot_mean_sync(sc, gid_e):
+    """Broadcast the sampled slots' mean client segment to every global
+    row (SFLv2's single global client segment under participation)."""
+    w_slots = (gid_e >= 0).astype(jnp.float32)
+    rows = jax.tree.map(lambda x: x[jnp.maximum(gid_e, 0)], sc)
+    wn = w_slots / jnp.maximum(w_slots.sum(), 1.0)
+
+    def leaf(x):
+        wx = wn.reshape((-1,) + (1,) * (x.ndim - 1))
+        return (x.astype(jnp.float32) * wx).sum(axis=0)
+
+    m = jax.tree.map(leaf, rows)
+    return jax.tree.map(
+        lambda x, mm: jnp.broadcast_to(mm.astype(x.dtype)[None], x.shape),
+        sc, m)
 
 
 def make_interleaved_run_participation(adapter: SplitAdapter,
@@ -885,8 +932,9 @@ def make_interleaved_run_participation(adapter: SplitAdapter,
     step, keyed = split_step_fn(adapter, opt_client, opt_server, transport,
                                 privacy)
 
-    def run(stacked_clients, server, stacked_c_opts, s_opt, batches, ex_w,
-            sched, key_idx, base_key, slot_gid):
+    def interleaved_run_participation(stacked_clients, server,
+                                      stacked_c_opts, s_opt, batches, ex_w,
+                                      sched, key_idx, base_key, slot_gid):
         def round_body(carry, xs):
             sc0, sp0, co0, so0 = carry
             b_e, w_e, sched_e, ki_e, gid_e = xs
@@ -913,18 +961,8 @@ def make_interleaved_run_participation(adapter: SplitAdapter,
             (sc, sp, co, so), losses = jax.lax.scan(
                 body, (sc0, sp0, co0, so0), (sched_e, ki_e))
             if sync_clients:
-                w_slots = (gid_e >= 0).astype(jnp.float32)
-                rows = jax.tree.map(lambda x: x[jnp.maximum(gid_e, 0)], sc)
-                wn = w_slots / jnp.maximum(w_slots.sum(), 1.0)
-
-                def leaf(x):
-                    wx = wn.reshape((-1,) + (1,) * (x.ndim - 1))
-                    return (x.astype(jnp.float32) * wx).sum(axis=0)
-
-                m = jax.tree.map(leaf, rows)
-                sc = jax.tree.map(
-                    lambda x, mm: jnp.broadcast_to(
-                        mm.astype(x.dtype)[None], x.shape), sc, m)
+                with jax.named_scope("update"):
+                    sc = _slot_mean_sync(sc, gid_e)
             return (sc, sp, co, so), losses
 
         carry, losses = jax.lax.scan(
@@ -932,7 +970,8 @@ def make_interleaved_run_participation(adapter: SplitAdapter,
             (batches, ex_w, sched, key_idx, slot_gid))
         return (*carry, losses)
 
-    return _donating_jit(run, donate_argnums=(0, 1, 2, 3, 4))
+    return _donating_jit(interleaved_run_participation,
+                         donate_argnums=(0, 1, 2, 3, 4))
 
 
 def make_sflv3_run_participation(adapter: SplitAdapter,
@@ -976,8 +1015,9 @@ def make_sflv3_run_participation(adapter: SplitAdapter,
             lambda x, y, r: x.at[gid].set(y) if r else y,
             full, rows, rowwise(full))
 
-    def run(stacked_clients, server, c_opt, s_opt, batches, b_idx, key_idx,
-            step_valid, base_key, slot_gid):
+    def sflv3_run_participation(stacked_clients, server, c_opt, s_opt,
+                                batches, b_idx, key_idx, step_valid,
+                                base_key, slot_gid):
         def round_body(carry, xs):
             sc, sp, co, so = carry
             b_e, bi_e, ki_e, sv_e, gid_e = xs
@@ -1000,10 +1040,11 @@ def make_sflv3_run_participation(adapter: SplitAdapter,
             sc = scatter(sc, sck, gid_e)
             co = scatter(co, cok, gid_e)
             if sync_clients:
-                m = jax.tree.map(lambda x: x.mean(axis=0), sck)
-                sc = jax.tree.map(
-                    lambda x, mm: jnp.broadcast_to(mm[None], x.shape),
-                    sc, m)
+                with jax.named_scope("update"):
+                    m = jax.tree.map(lambda x: x.mean(axis=0), sck)
+                    sc = jax.tree.map(
+                        lambda x, mm: jnp.broadcast_to(mm[None], x.shape),
+                        sc, m)
             return (sc, sp, co, so), losses
 
         carry, losses = jax.lax.scan(
@@ -1011,7 +1052,8 @@ def make_sflv3_run_participation(adapter: SplitAdapter,
             (batches, b_idx, key_idx, step_valid, slot_gid))
         return (*carry, losses)
 
-    return _donating_jit(run, donate_argnums=(0, 1, 2, 3, 4))
+    return _donating_jit(sflv3_run_participation,
+                         donate_argnums=(0, 1, 2, 3, 4))
 
 
 # ---------------------------------------------------------------------------

@@ -133,10 +133,13 @@ class FedAvg(Strategy):
         from repro.core.strategies import engine as ENG
         tel = self._tel
         place = self.placement
-        with self._span("pack"):
+        with self._span("pack") as sp:
             packed = ENG.pack_epoch(client_data, batch_size, rng,
                                     self.drop_remainder,
-                                    pad_clients=place.n_pad)
+                                    pad_clients=place.n_pad,
+                                    span=self._span)
+            self._pack_span(sp, packed.batches, packed.mask.size,
+                            sum(packed.n_batches))
         if packed.nb_max == 0:
             return state, EpochLog([], 0,
                                    client_steps=[0] * self.n_clients)
@@ -152,13 +155,19 @@ class FedAvg(Strategy):
                                           self.privacy, place, tel))
         key_idx = place.put(ENG.key_index_grid(self, packed))
         batches = place.put(packed.batches)
-        with self._span("dispatch"):
-            out = epoch_fn(
-                state["params"], batches, place.put(packed.mask),
-                place.put(packed.ex_weights), key_idx,
-                self._privacy_base_key())
-        self._count_dispatch()
-        locals_stacked, losses = out[0], out[1]
+        out = self._enqueue(epoch_fn, (
+            state["params"], batches, place.put(packed.mask),
+            place.put(packed.ex_weights), key_idx,
+            self._privacy_base_key()), stash=False)
+        locals_stacked, losses = out[0], self._wait(out[1])
+        with self._span("account"):
+            return state, self._aggregate_epoch(
+                state, out, locals_stacked, losses, packed, batch_size, tel)
+
+    def _aggregate_epoch(self, state, out, locals_stacked, losses, packed,
+                         batch_size, tel):
+        """Host aggregation and accounting of one compiled FL epoch."""
+        from repro.core.strategies import engine as ENG
         old_gp = state["params"]
         # the aggregator's host path: the default WeightedMean dispatches
         # the exact pre-refactor jitted weighted mean; SecAggregator
@@ -178,7 +187,7 @@ class FedAvg(Strategy):
             log.telemetry = self._round_telemetry(
                 tel, losses, {k: np.asarray(v) for k, v in out[2].items()},
                 packed.mask, old_gp, locals_stacked, state["params"])
-        return state, log
+        return log
 
     @property
     def _whole_run(self):
@@ -205,10 +214,13 @@ class FedAvg(Strategy):
                                            batch_size, n_epochs)
         tel = self._tel
         place = self.placement
-        with self._span("pack"):
+        with self._span("pack") as sp:
             batches, packed = ENG.pack_run(client_data, batch_size, rng,
                                            n_epochs, self.drop_remainder,
-                                           pad_clients=place.n_pad)
+                                           pad_clients=place.n_pad,
+                                           span=self._span)
+            self._pack_span(sp, batches, n_epochs * packed.mask.size,
+                            n_epochs * sum(packed.n_batches))
         if tel is None:
             if not hasattr(self, "_run_c"):
                 self._run_c = ENG.make_fl_run(
@@ -227,34 +239,36 @@ class FedAvg(Strategy):
                 place.put(packed.mask), place.put(packed.ex_weights),
                 place.put(key_idx, axis=1), self._privacy_base_key(),
                 np.asarray(packed.n_samples, np.float32))
-        with self._span("dispatch"):
-            if tel is None:
-                state["params"], losses = run_fn(*args)
-            else:
-                state["params"], (losses, met) = run_fn(*args)
-        self._count_dispatch()
-        self._last_run_invocation = (run_fn, ENG.abstract_args(args))
+        if tel is None:
+            state["params"], losses = self._enqueue(run_fn, args)
+        else:
+            state["params"], (losses, met) = self._enqueue(run_fn, args)
         self._run_calls = getattr(self, "_run_calls", 0) + 1
-        losses = np.asarray(losses)
-        logs = []
-        for e in range(n_epochs):
-            flat, loss_w = ENG.client_major_log(losses[e], packed)
-            logs.append(EpochLog(flat, len(flat), weights=loss_w,
-                                 client_steps=list(
-                                     packed.n_batches[:self.n_clients])))
-        if tel is not None:
-            from repro.obs import telemetry as T
-            met = {k: np.asarray(v) for k, v in met.items()}
-            extra = ({"update_cosine": met.pop("update_cosine")}
-                     if "update_cosine" in met else None)
-            rounds = T.rounds_client_major(tel, losses, met, packed.mask,
-                                           self.n_clients, extra)
-            for log, r in zip(logs, rounds):
-                log.telemetry = r
-        for ci, nb in enumerate(packed.n_batches):
-            if nb:
-                self._dp_account(ci, packed.n_samples[ci], batch_size,
-                                 count=nb * n_epochs)
+        losses = self._wait(losses)
+        with self._span("account"):
+            logs = []
+            for e in range(n_epochs):
+                flat, loss_w = ENG.client_major_log(losses[e], packed)
+                logs.append(EpochLog(flat, len(flat), weights=loss_w,
+                                     client_steps=list(
+                                         packed.n_batches[:self.n_clients])))
+            if tel is not None:
+                from repro.obs import telemetry as T
+                met = {k: np.asarray(v) for k, v in met.items()}
+                extra = ({"update_cosine": met.pop("update_cosine")}
+                         if "update_cosine" in met else None)
+                rounds = T.rounds_client_major(tel, losses, met,
+                                               packed.mask, self.n_clients,
+                                               extra)
+                for log, r in zip(logs, rounds):
+                    log.telemetry = r
+            for ci, nb in enumerate(packed.n_batches):
+                if nb:
+                    self._dp_account(ci, packed.n_samples[ci], batch_size,
+                                     count=nb * n_epochs)
+            # the run's host batches and donated inputs are freed here,
+            # inside "account", not in the frame's teardown after it
+            del args, batches, packed
         return state, logs
 
     def _run_participation(self, state, client_data, rng, batch_size,
@@ -270,10 +284,11 @@ class FedAvg(Strategy):
         from repro.core.strategies import engine as ENG
         part = self.participation
         tel = self._tel
-        with self._span("pack"):
+        with self._span("pack") as sp:
             batches, pack = ENG.pack_participation_run(
                 client_data, batch_size, rng, n_epochs, part,
-                self.drop_remainder)
+                self.drop_remainder, span=self._span)
+            self._pack_span(sp, batches, pack.mask.size, pack.mask.sum())
         nbs = pack.n_batches
         T_N = int(sum(nbs))
         prefix = np.concatenate([[0], np.cumsum(nbs)[:-1]]).astype(np.int64)
@@ -303,15 +318,26 @@ class FedAvg(Strategy):
         args = (state["params"], batches, pack.mask, pack.ex_weights,
                 key_idx, self._privacy_base_key(), pack.agg_w,
                 pack.staleness, pack.slot_gid)
-        with self._span("dispatch"):
-            if tel is None:
-                state["params"], losses = run_fn(*args)
-            else:
-                state["params"], (losses, met) = run_fn(*args)
-        self._count_dispatch()
-        self._last_run_invocation = (run_fn, ENG.abstract_args(args))
+        met = None
+        if tel is None:
+            state["params"], losses = self._enqueue(run_fn, args)
+        else:
+            state["params"], (losses, met) = self._enqueue(run_fn, args)
         self._run_calls = getattr(self, "_run_calls", 0) + 1
-        losses = np.asarray(losses)
+        losses = self._wait(losses)
+        with self._span("account"):
+            logs = self._account_participation(
+                losses, met, pack, part, batch_size, n_epochs, tel)
+            # the run's host batches and donated inputs are freed here,
+            # inside "account", not in the frame's teardown after it
+            del args, batches, pack
+        return state, logs
+
+    def _account_participation(self, losses, met, pack, part, batch_size,
+                               n_epochs, tel):
+        """Epoch logs, telemetry and DP accounting of a participating
+        run."""
+        nbs = pack.n_batches
         logs = []
         for e in range(n_epochs):
             flat, loss_w = [], []
@@ -350,7 +376,7 @@ class FedAvg(Strategy):
                     self._dp_account(g, pack.n_samples[g], batch_size,
                                      count=nbs[g] * n_epochs,
                                      q_scale=part.rate)
-        return state, logs
+        return logs
 
     def params_for_eval(self, state, client_idx):
         return state["params"]

@@ -146,10 +146,13 @@ class SplitLearning(Strategy):
         from repro.core.strategies import engine as ENG
         tel = self._tel
         place = self.placement
-        with self._span("pack"):
+        with self._span("pack") as sp:
             packed = ENG.pack_epoch(client_data, batch_size, rng,
                                     self.drop_remainder,
-                                    pad_clients=place.n_pad)
+                                    pad_clients=place.n_pad,
+                                    span=self._span)
+            self._pack_span(sp, packed.batches, packed.mask.size,
+                            sum(packed.n_batches))
         sched = schedule_array(self.schedule, packed.n_batches)
         if len(sched) == 0:
             self._end_of_epoch(state)        # SFLv2 still syncs clients
@@ -170,29 +173,29 @@ class SplitLearning(Strategy):
         key_idx = (self._take_key_indices(len(sched)) if self._keyed
                    else np.zeros((len(sched),), np.uint32))
         self._ensure_stacked(state)
-        with self._span("dispatch"):
-            out = epoch_fn(
-                state["stacked_clients"], state["server"],
-                state["stacked_c_opts"], state["s_opt"],
-                place.put(packed.batches), place.put(packed.ex_weights),
-                sched, key_idx, self._privacy_base_key())
-        self._count_dispatch()
+        out = self._enqueue(epoch_fn, (
+            state["stacked_clients"], state["server"],
+            state["stacked_c_opts"], state["s_opt"],
+            place.put(packed.batches), place.put(packed.ex_weights),
+            sched, key_idx, self._privacy_base_key()), stash=False)
         (state["stacked_clients"], state["server"],
          state["stacked_c_opts"], state["s_opt"], losses) = out[:5]
-        flat, loss_w = ENG.scheduled_log(losses, sched, packed)
-        # the interleave program's output sharding is compiler-chosen:
-        # re-place so between-epoch state is always on the hosp mesh
-        state["stacked_clients"] = place.put(state["stacked_clients"])
-        state["stacked_c_opts"] = place.put(state["stacked_c_opts"])
-        self._account_compiled(packed, batch_size)
-        self._end_of_epoch(state)
-        log = EpochLog(flat, len(flat), weights=loss_w,
-                       client_steps=list(
-                           packed.n_batches[:self.n_clients]))
-        if tel is not None:
-            log.telemetry = self._round_telemetry(
-                tel, np.asarray(losses),
-                {k: np.asarray(v) for k, v in out[5].items()}, sched)
+        losses = self._wait(losses)
+        with self._span("account"):
+            flat, loss_w = ENG.scheduled_log(losses, sched, packed)
+            # the interleave program's output sharding is compiler-chosen:
+            # re-place so between-epoch state is always on the hosp mesh
+            state["stacked_clients"] = place.put(state["stacked_clients"])
+            state["stacked_c_opts"] = place.put(state["stacked_c_opts"])
+            self._account_compiled(packed, batch_size)
+            self._end_of_epoch(state)
+            log = EpochLog(flat, len(flat), weights=loss_w,
+                           client_steps=list(
+                               packed.n_batches[:self.n_clients]))
+            if tel is not None:
+                log.telemetry = self._round_telemetry(
+                    tel, losses,
+                    {k: np.asarray(v) for k, v in out[5].items()}, sched)
         return state, log
 
     @property
@@ -208,10 +211,13 @@ class SplitLearning(Strategy):
                                            batch_size, n_epochs)
         tel = self._tel
         place = self.placement
-        with self._span("pack"):
+        with self._span("pack") as sp:
             batches, packed = ENG.pack_run(client_data, batch_size, rng,
                                            n_epochs, self.drop_remainder,
-                                           pad_clients=place.n_pad)
+                                           pad_clients=place.n_pad,
+                                           span=self._span)
+            self._pack_span(sp, batches, n_epochs * packed.mask.size,
+                            n_epochs * sum(packed.n_batches))
         sched = schedule_array(self.schedule, packed.n_batches)
         sync_w = place.client_weights() if place.padded else None
         if tel is None:
@@ -237,30 +243,32 @@ class SplitLearning(Strategy):
                 state["stacked_c_opts"], state["s_opt"],
                 place.put(batches, axis=1), place.put(packed.ex_weights),
                 sched, key_idx, self._privacy_base_key())
-        with self._span("dispatch"):
-            out = run_fn(*args)
-        self._count_dispatch()
-        self._last_run_invocation = (run_fn, ENG.abstract_args(args))
+        out = self._enqueue(run_fn, args)
         (state["stacked_clients"], state["server"],
          state["stacked_c_opts"], state["s_opt"], losses) = out[:5]
         self._run_calls = getattr(self, "_run_calls", 0) + 1
-        state["stacked_clients"] = place.put(state["stacked_clients"])
-        state["stacked_c_opts"] = place.put(state["stacked_c_opts"])
-        losses = np.asarray(losses)
-        logs = []
-        for e in range(n_epochs):
-            flat, loss_w = ENG.scheduled_log(losses[e], sched, packed)
-            logs.append(EpochLog(flat, len(flat), weights=loss_w,
-                                 client_steps=list(
-                                     packed.n_batches[:self.n_clients])))
-        if tel is not None:
-            from repro.obs import telemetry as T
-            rounds = T.rounds_scheduled(
-                tel, losses, {k: np.asarray(v) for k, v in out[5].items()},
-                sched, self.n_clients)
-            for log, r in zip(logs, rounds):
-                log.telemetry = r
-        self._account_compiled(packed, batch_size, n_epochs)
+        losses = self._wait(losses)
+        with self._span("account"):
+            state["stacked_clients"] = place.put(state["stacked_clients"])
+            state["stacked_c_opts"] = place.put(state["stacked_c_opts"])
+            logs = []
+            for e in range(n_epochs):
+                flat, loss_w = ENG.scheduled_log(losses[e], sched, packed)
+                logs.append(EpochLog(flat, len(flat), weights=loss_w,
+                                     client_steps=list(
+                                         packed.n_batches[:self.n_clients])))
+            if tel is not None:
+                from repro.obs import telemetry as T
+                rounds = T.rounds_scheduled(
+                    tel, losses,
+                    {k: np.asarray(v) for k, v in out[5].items()},
+                    sched, self.n_clients)
+                for log, r in zip(logs, rounds):
+                    log.telemetry = r
+            self._account_compiled(packed, batch_size, n_epochs)
+            # the run's host batches and donated inputs are freed here,
+            # inside "account", not in the frame's teardown after it
+            del args, batches, packed, out
         return state, logs
 
     def _run_participation(self, state, client_data, rng, batch_size,
@@ -276,10 +284,11 @@ class SplitLearning(Strategy):
             raise ValueError("participation with observe is not supported "
                              "for the split family")
         part = self.participation
-        with self._span("pack"):
+        with self._span("pack") as sp:
             batches, pack = ENG.pack_participation_run(
                 client_data, batch_size, rng, n_epochs, part,
-                self.drop_remainder)
+                self.drop_remainder, span=self._span)
+            self._pack_span(sp, batches, pack.mask.size, pack.mask.sum())
         nbs = pack.n_batches
         full_sched = schedule_array(self.schedule, nbs)
         S_N = len(full_sched)
@@ -315,14 +324,23 @@ class SplitLearning(Strategy):
                 state["stacked_c_opts"], state["s_opt"], batches,
                 pack.ex_weights, sched, key_idx,
                 self._privacy_base_key(), pack.slot_gid)
-        with self._span("dispatch"):
-            out = run_fn(*args)
-        self._count_dispatch()
-        self._last_run_invocation = (run_fn, ENG.abstract_args(args))
+        out = self._enqueue(run_fn, args)
         (state["stacked_clients"], state["server"],
          state["stacked_c_opts"], state["s_opt"], losses) = out[:5]
         self._run_calls = getattr(self, "_run_calls", 0) + 1
-        losses = np.asarray(losses)
+        losses = self._wait(losses)
+        with self._span("account"):
+            logs = self._account_participation(
+                losses, rounds, pack, part, batch_size, n_epochs, batches)
+            # the run's host batches and donated inputs are freed here,
+            # inside "account", not in the frame's teardown after it
+            del args, batches, pack, out
+        return state, logs
+
+    def _account_participation(self, losses, rounds, pack, part,
+                               batch_size, n_epochs, batches):
+        """Epoch logs, DP and wire accounting of a participating run."""
+        nbs = pack.n_batches
         logs = []
         for e, rows in enumerate(rounds):
             gid = pack.slot_gid[e]
@@ -359,7 +377,7 @@ class SplitLearning(Strategy):
                         self.transport.account(self.adapter, b,
                                                count=int(n_steps))
                 self._record_wire_epoch(example, counts, client_set=ids)
-        return state, logs
+        return logs
 
     def _account_compiled(self, packed, batch_size, n_epochs=1):
         """Analytic accounting for the compiled path: the DP accountant

@@ -170,9 +170,12 @@ class SplitFedV3(SplitLearning):
         from repro.core.strategies import engine as ENG
         tel = self._tel
         place = self.placement
-        with self._span("pack"):
+        with self._span("pack") as sp:
             packed = ENG.pack_epoch(client_data, batch_size, rng, True,
-                                    pad_clients=place.n_pad)
+                                    pad_clients=place.n_pad,
+                                    span=self._span)
+            self._pack_span(sp, packed.batches, packed.mask.size,
+                            sum(packed.n_batches))
         self._check_batches(packed.n_batches[:self.n_clients], batch_size)
         steps = packed.nb_max
         if tel is None:
@@ -200,23 +203,23 @@ class SplitFedV3(SplitLearning):
         batches = place.put(packed.batches)
         sc = place.put(state["stacked_clients"])
         c_opt = place.put(state["c_opt"])
-        with self._span("dispatch"):
-            out = epoch_fn(
-                sc, state["server"], c_opt, state["s_opt"], batches,
-                place.put(b_idx, axis=1), key_idx,
-                self._privacy_base_key())
-        self._count_dispatch()
+        out = self._enqueue(epoch_fn, (
+            sc, state["server"], c_opt, state["s_opt"], batches,
+            place.put(b_idx, axis=1), key_idx, self._privacy_base_key()),
+            stash=False)
         (state["stacked_clients"], state["server"], state["c_opt"],
          state["s_opt"], losses) = out[:5]
-        flat = np.asarray(losses)[:, :self.n_clients].reshape(-1).tolist()
-        self._account_v3(packed, batch_size)
-        self._end_of_epoch(state)
-        log = EpochLog(flat, steps,
-                       client_steps=[steps] * self.n_clients)
-        if tel is not None:
-            log.telemetry = self._sync_round_telemetry(
-                tel, np.asarray(losses),
-                {k: np.asarray(v) for k, v in out[5].items()})
+        losses = self._wait(losses)
+        with self._span("account"):
+            flat = losses[:, :self.n_clients].reshape(-1).tolist()
+            self._account_v3(packed, batch_size)
+            self._end_of_epoch(state)
+            log = EpochLog(flat, steps,
+                           client_steps=[steps] * self.n_clients)
+            if tel is not None:
+                log.telemetry = self._sync_round_telemetry(
+                    tel, losses,
+                    {k: np.asarray(v) for k, v in out[5].items()})
         return state, log
 
     def _account_v3(self, packed, batch_size, n_epochs=1):
@@ -245,10 +248,13 @@ class SplitFedV3(SplitLearning):
                                            batch_size, n_epochs)
         tel = self._tel
         place = self.placement
-        with self._span("pack"):
+        with self._span("pack") as sp:
             batches, packed = ENG.pack_run(client_data, batch_size, rng,
                                            n_epochs, True,
-                                           pad_clients=place.n_pad)
+                                           pad_clients=place.n_pad,
+                                           span=self._span)
+            self._pack_span(sp, batches, n_epochs * packed.mask.size,
+                            n_epochs * sum(packed.n_batches))
         self._check_batches(packed.n_batches[:self.n_clients], batch_size)
         steps = packed.nb_max
         if tel is None:
@@ -280,25 +286,28 @@ class SplitFedV3(SplitLearning):
                 place.put(state["c_opt"]), state["s_opt"],
                 place.put(batches, axis=1), place.put(b_idx, axis=1),
                 key_idx, self._privacy_base_key())
-        with self._span("dispatch"):
-            out = run_fn(*args)
-        self._count_dispatch()
-        self._last_run_invocation = (run_fn, ENG.abstract_args(args))
+        out = self._enqueue(run_fn, args)
         (state["stacked_clients"], state["server"], state["c_opt"],
          state["s_opt"], losses) = out[:5]
         self._run_calls = getattr(self, "_run_calls", 0) + 1
-        losses = np.asarray(losses)
-        logs = [EpochLog(losses[e, :, :self.n_clients].reshape(-1).tolist(),
-                         steps, client_steps=[steps] * self.n_clients)
+        losses = self._wait(losses)
+        with self._span("account"):
+            logs = [EpochLog(
+                losses[e, :, :self.n_clients].reshape(-1).tolist(), steps,
+                client_steps=[steps] * self.n_clients)
                 for e in range(n_epochs)]
-        if tel is not None:
-            from repro.obs import telemetry as T
-            rounds = T.rounds_sync(
-                tel, losses, {k: np.asarray(v) for k, v in out[5].items()},
-                self.n_clients)
-            for log, r in zip(logs, rounds):
-                log.telemetry = r
-        self._account_v3(packed, batch_size, n_epochs)
+            if tel is not None:
+                from repro.obs import telemetry as T
+                rounds = T.rounds_sync(
+                    tel, losses,
+                    {k: np.asarray(v) for k, v in out[5].items()},
+                    self.n_clients)
+                for log, r in zip(logs, rounds):
+                    log.telemetry = r
+            self._account_v3(packed, batch_size, n_epochs)
+            # the run's host batches and donated inputs are freed here,
+            # inside "account", not in the frame's teardown after it
+            del args, batches, packed, out
         return state, logs
 
     def _run_participation(self, state, client_data, rng, batch_size,
@@ -321,9 +330,12 @@ class SplitFedV3(SplitLearning):
             raise ValueError("participation with observe is not supported "
                              "for the split family")
         part = self.participation
-        with self._span("pack"):
+        with self._span("pack") as sp:
             batches, pack = ENG.pack_participation_run(
-                client_data, batch_size, rng, n_epochs, part, True)
+                client_data, batch_size, rng, n_epochs, part, True,
+                span=self._span)
+            self._pack_span(sp, batches, pack.mask.size,
+                            pack.mask.sum())
         nbs = pack.n_batches
         self._check_batches(nbs, batch_size)
         NB_N, S = pack.nb_max, pack.n_slots
@@ -354,14 +366,25 @@ class SplitFedV3(SplitLearning):
         args = (state["stacked_clients"], state["server"], state["c_opt"],
                 state["s_opt"], batches, b_idx, key_idx, step_valid,
                 self._privacy_base_key(), pack.slot_gid)
-        with self._span("dispatch"):
-            out = run_fn(*args)
-        self._count_dispatch()
-        self._last_run_invocation = (run_fn, ENG.abstract_args(args))
+        out = self._enqueue(run_fn, args)
         (state["stacked_clients"], state["server"], state["c_opt"],
          state["s_opt"], losses) = out[:5]
         self._run_calls = getattr(self, "_run_calls", 0) + 1
-        losses = np.asarray(losses)
+        losses = self._wait(losses)
+        with self._span("account"):
+            logs = self._account_participation(
+                losses, pack, part, real_steps, batch_size, n_epochs,
+                batches)
+            # the run's host batches and donated inputs are freed here,
+            # inside "account", not in the frame's teardown after it
+            del args, batches, pack, out
+        return state, logs
+
+    def _account_participation(self, losses, pack, part, real_steps,
+                               batch_size, n_epochs, batches):
+        """Epoch logs, DP and wire accounting of a participating run."""
+        nbs = pack.n_batches
+        NB_N = pack.nb_max
         logs = []
         for e in range(n_epochs):
             rs = real_steps[e]
@@ -386,7 +409,7 @@ class SplitFedV3(SplitLearning):
                     counts[int(g)] = rs
                     self.transport.account(self.adapter, example, count=rs)
                 self._record_wire_epoch(example, counts, client_set=ids)
-        return state, logs
+        return logs
 
     def _end_of_epoch(self, state):
         pass

@@ -258,7 +258,7 @@ def test_step_keys_static_sets():
 
 
 # ---------------------------------------------------------------------------
-# trace.py: span tree, synthetic round slices, wire lanes, merged JSON
+# trace.py: span tree, wire lanes, merged JSON
 # ---------------------------------------------------------------------------
 
 def test_tracer_span_tree():
@@ -267,14 +267,17 @@ def test_tracer_span_tree():
     with tr.span("run", strategy="fl"):
         with tr.span("pack"):
             pass
-        with tr.span("dispatch"):
+        with tr.span("enqueue"):
             pass
     names = [e["name"] for e in tr.events]
-    assert names == ["pack", "dispatch", "run"]   # children close first
+    assert names == ["pack", "enqueue", "run"]   # children close first
     run = tr.find("run")
-    disp = tr.find("dispatch")
+    disp = tr.find("enqueue")
     assert run["args"]["strategy"] == "fl" and run["args"]["depth"] == 0
     assert disp["args"]["depth"] == 1
+    # the spans of one run share its ordinal and name their parent
+    assert run["args"]["run"] == disp["args"]["run"] == 1
+    assert disp["args"]["parent"] == "run" and run["args"]["parent"] is None
     # children nest inside the parent span's interval
     assert run["ts"] <= disp["ts"]
     assert disp["ts"] + disp["dur"] <= run["ts"] + run["dur"] + 1.0
@@ -292,26 +295,7 @@ def test_strategy_records_spans(tiny_setup):
            4, 2)
     assert tr.find("run") is not None
     assert tr.find("pack") is not None
-    assert tr.find("dispatch") is not None
-
-
-def test_round_events_synthetic_slices(tiny_setup):
-    from repro.obs.trace import round_events
-    rt = _run(tiny_setup, "fl", "compiled", observed=True,
-              privacy=DP)["rt"]
-    span = {"ts": 100.0, "dur": 50.0}
-    evs = round_events(rt, span)
-    slices = [e for e in evs if e["ph"] == "X"]
-    counters = [e for e in evs if e["ph"] == "C"]
-    assert len(slices) == EPOCHS and len(counters) == EPOCHS
-    for i, e in enumerate(slices):
-        assert e["args"]["synthetic"] is True
-        assert e["ts"] == pytest.approx(100.0 + i * 25.0)
-        assert e["dur"] == pytest.approx(25.0)
-        assert "loss" in e["args"]
-    assert set(counters[0]["args"]) == {"hospital0", "hospital1",
-                                        "hospital2"}
-    assert round_events(type(rt)("fl", 3, []), span) == []
+    assert tr.find("enqueue") is not None
 
 
 def test_wire_events_and_merge(tmp_path):
