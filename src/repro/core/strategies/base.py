@@ -322,7 +322,7 @@ class Strategy:
 
     def attach_tracer(self, tracer):
         """Attach a ``repro.obs.trace.Tracer`` (``None`` detaches): each
-        run records ``run -> pack{gather, stack} -> enqueue -> wait ->
+        run records ``run -> pack{gather[, stack]} -> enqueue -> wait ->
         account`` spans with their counters, and every compile while it
         is attached lands as a ``compile.*`` span."""
         if self._tracer is not None:
@@ -340,13 +340,19 @@ class Strategy:
         return self._tracer.span(name, **args)
 
     @staticmethod
-    def _pack_span(sp, batches: dict, slots: int, real: int):
-        """The ``pack`` span's counters, when traced: bytes packed per
-        data key, batch slots packed and the real (non-padding) batches
-        among them."""
+    def _pack_span(sp, batches: dict, slots: int, real: int,
+                   device_gather: int = 0):
+        """The ``pack`` span's counters, when traced: bytes handed to the
+        program per data key (a packed grid, or the hospitals' arrays and
+        the ``index`` grid), batch slots packed, the real (non-padding)
+        batches among them, and the batch slots the program gathers on
+        the device (0 where the host packed them)."""
         if sp is not None:
-            sp.set(**{f"bytes_{k}": int(v.nbytes) for k, v in batches.items()},
-                   batch_slots=int(slots), real_batches=int(real))
+            sp.set(**{f"bytes_{k}": sum(int(a.nbytes)
+                                        for a in jax.tree.leaves(v))
+                      for k, v in batches.items()},
+                   batch_slots=int(slots), real_batches=int(real),
+                   device_gather=int(device_gather))
 
     def _enqueue(self, fn, args, stash: bool = True):
         """Call a compiled program under the ``enqueue`` span: the jitted

@@ -12,6 +12,11 @@ into a single XLA program instead:
     ``[n_clients, n_batches]`` validity mask; uneven hospital sizes become
     masked (no-op) scan steps and, with ``drop_remainder=False``, the final
     short batch becomes per-example weights instead of a ragged shape.
+    An unsharded SFLv3/v1 whole run takes the **index layout** instead
+    (``pack_run_index``): the hospitals' own arrays and an int32
+    ``[n_epochs, n_clients, n_batches, batch]`` grid of row ids, from
+    which the program gathers each step's batch on the device; the host
+    copies no image bytes.  Every other run takes the packed grid.
   * **scan over batches, vmap over hospitals** where semantics allow it:
     FL local epochs are independent per hospital, so the per-client
     ``lax.scan`` is wrapped in a ``vmap`` over the stacked hospital axis.
@@ -81,6 +86,7 @@ class PackedEpoch:
 
     ``batches[k]`` has shape ``[n_clients, nb_max, batch, ...]``; rows past
     a hospital's real data are zero padding flagged invalid by ``mask``.
+    ``pack_run_index`` returns the meta alone, with ``batches`` empty.
     ``ex_weights`` (only with ``drop_remainder=False``) carries per-example
     validity for the final short batch of each hospital.
     """
@@ -136,7 +142,12 @@ def pack_epoch(client_data: list, batch_size: int,
                            pad_clients)
 
 
-def _pack_epoch(client_data, batch_size, rng, drop_remainder, pad_clients):
+def _shuffle_epoch(client_data, batch_size, rng, drop_remainder,
+                   pad_clients=0):
+    """One epoch's shuffled row ids per hospital, cut to the rows its
+    batches use, and the epoch's ``PackedEpoch`` meta with ``batches``
+    still empty.  Consumes ``rng`` in hospital order, as ``np_batches``
+    does."""
     n_batches, n_samples, step_examples, order = [], [], [], []
     for d in client_data:
         n = len(next(iter(d.values())))
@@ -145,7 +156,8 @@ def _pack_epoch(client_data, batch_size, rng, drop_remainder, pad_clients):
             rng.shuffle(idx)
         nb, nb_full, rem = _client_batch_count(n, batch_size,
                                                drop_remainder)
-        order.append(idx)
+        used = nb_full * batch_size if drop_remainder else n
+        order.append(idx[:used])
         n_batches.append(nb)
         n_samples.append(n)
         step_examples.append([batch_size] * nb_full
@@ -156,16 +168,6 @@ def _pack_epoch(client_data, batch_size, rng, drop_remainder, pad_clients):
     step_examples += [[] for _ in range(pad_clients)]
     C = len(client_data) + pad_clients
 
-    batches = {}
-    for k in client_data[0]:
-        proto = client_data[0][k]
-        out = np.zeros((C, NB * batch_size, *proto.shape[1:]), proto.dtype)
-        for c, d in enumerate(client_data):
-            used = (n_batches[c] * batch_size if drop_remainder
-                    else n_samples[c])
-            out[c, :used] = d[k][order[c][:used]]
-        batches[k] = out.reshape(C, NB, batch_size, *proto.shape[1:])
-
     mask = np.zeros((C, NB), bool)
     ex_w = (None if drop_remainder
             else np.zeros((C, NB, batch_size), np.float32))
@@ -174,8 +176,21 @@ def _pack_epoch(client_data, batch_size, rng, drop_remainder, pad_clients):
         if ex_w is not None:
             for j, m in enumerate(step_examples[c]):
                 ex_w[c, j, :m] = 1.0
-    return PackedEpoch(batches, mask, ex_w, n_batches, step_examples,
-                       n_samples, batch_size)
+    return order, PackedEpoch({}, mask, ex_w, n_batches, step_examples,
+                              n_samples, batch_size)
+
+
+def _pack_epoch(client_data, batch_size, rng, drop_remainder, pad_clients):
+    order, packed = _shuffle_epoch(client_data, batch_size, rng,
+                                   drop_remainder, pad_clients)
+    C, NB = packed.mask.shape
+    for k in client_data[0]:
+        proto = client_data[0][k]
+        out = np.zeros((C, NB * batch_size, *proto.shape[1:]), proto.dtype)
+        for c, d in enumerate(client_data):
+            out[c, :len(order[c])] = d[k][order[c]]
+        packed.batches[k] = out.reshape(C, NB, batch_size, *proto.shape[1:])
+    return packed
 
 
 # ---------------------------------------------------------------------------
@@ -374,12 +389,18 @@ def _sflv3_epoch_body(adapter: SplitAdapter, opt_client: O.Optimizer,
                                     client_weights, telemetry=telemetry)
 
     def chunk_epoch(stacked_clients, server, c_opt, s_opt, batches, b_idx,
-                    key_idx, base_key):
+                    key_idx, base_key, rows=None):
+        # rows ([C, NB, B] global row ids) given: ``batches`` holds the
+        # hospitals' rows laid end to end and each step gathers from it
         def body(carry, xs):
             sc, sp, co, so = carry
             bi, ki = xs
-            batch = jax.tree.map(
-                lambda x: x[jnp.arange(local), bi], batches)
+            if rows is None:
+                batch = jax.tree.map(
+                    lambda x: x[jnp.arange(local), bi], batches)
+            else:
+                r = rows[jnp.arange(local), bi]
+                batch = jax.tree.map(lambda x: x[r], batches)
             out = step(sc, sp, co, so, batch,
                        _step_key(base_key, ki, keyed))
             ys = (out[4], out[5]) if observed else out[4]
@@ -473,7 +494,8 @@ def _donating_jit(fn, donate_argnums):
 
     The run carries (params / optimizer state, which the scan returns with
     identical shapes — XLA aliases them in place) and the packed
-    ``[E, C, NB, B, ...]`` batch stack (no aliasable output, but freeing it
+    ``[E, C, NB, B, ...]`` batch stack, or the hospital arrays an unsharded
+    SFLv3 run gathers from (no aliasable output, but freeing it
     at entry lets the allocator reuse the run's largest buffer as scratch)
     are dead to the caller the moment the run is dispatched: every strategy
     immediately overwrites its state with the outputs.  Donating them cuts
@@ -505,14 +527,17 @@ def abstract_args(args):
     program without the stash pinning the run's donated (deleted) buffers
     or the multi-epoch batch stack in memory.  A committed array keeps its
     sharding, so a ``shard=True`` run re-lowers as the sharded program and
-    the stash records where the run's inputs were placed.
+    the stash records where the run's inputs were placed.  A host leaf
+    gives the dtype ``jit`` would give it, read without a device copy.
     """
     def abstract(a):
         if isinstance(a, jax.ShapeDtypeStruct):
             return a
-        sharding = a.sharding if getattr(a, "committed", False) else None
-        return jax.ShapeDtypeStruct(np.shape(a), jnp.asarray(a).dtype,
-                                    sharding=sharding)
+        if isinstance(a, jax.Array):
+            sharding = a.sharding if a.committed else None
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+        return jax.ShapeDtypeStruct(
+            np.shape(a), jax.dtypes.canonicalize_dtype(np.result_type(a)))
 
     return jax.tree.map(abstract, args)
 
@@ -552,6 +577,40 @@ def pack_run(client_data, batch_size: int, rng, n_epochs: int,
         batches = {k: np.stack([p.batches[k] for p in packs])
                    for k in packs[0].batches}
     return batches, packs[0]
+
+
+def pack_run_index(client_data, batch_size: int, rng, n_epochs: int,
+                   drop_remainder: bool = True, pad_clients: int = 0,
+                   span=_no_span):
+    """``pack_run``'s batches as row indices: ``(data, idx, meta)``.
+
+    ``data[k]`` lists every hospital's ``k`` array as the caller gave it
+    (no copy, no padding).  ``idx`` is an int32 ``[n_epochs, n_clients,
+    nb_max, batch]`` grid of global row ids into the hospitals' rows laid
+    end to end; padding slots point at row 0 and carry no weight.  The
+    shuffles consume ``rng`` exactly as ``pack_run``'s do (epoch-major,
+    hospital order), so gathering ``idx`` from the concatenated rows gives
+    ``pack_run``'s batch grid row for row; ``meta`` is its ``PackedEpoch``
+    meta with ``batches`` empty.  ``pad_clients`` phantom hospitals (see
+    ``pack_epoch``) get all-False mask rows and index rows of row 0, where
+    the grid has zeros: zero-weight rows either way.  ``span`` times the
+    shuffles and the index grid as ``gather``.
+    """
+    with span("gather"):
+        sizes = [len(next(iter(d.values()))) for d in client_data]
+        offsets = np.cumsum([0] + sizes[:-1])
+        orders = []
+        for _ in range(n_epochs):
+            order, meta = _shuffle_epoch(client_data, batch_size, rng,
+                                         drop_remainder, pad_clients)
+            orders.append(order)
+        C, NB = meta.mask.shape
+        idx = np.zeros((n_epochs, C, NB * batch_size), np.int32)
+        for e, order in enumerate(orders):
+            for c, rows in enumerate(order):
+                idx[e, c, :len(rows)] = offsets[c] + rows
+        data = {k: [d[k] for d in client_data] for k in client_data[0]}
+    return data, idx.reshape(n_epochs, C, NB, batch_size), meta
 
 
 def make_fl_run(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
@@ -688,9 +747,16 @@ def make_sflv3_run(adapter: SplitAdapter, opt_client: O.Optimizer,
     ``b_idx`` is epoch-invariant); ``sync_clients`` folds SFLv1's client
     fed-averaging into the round body; ``client_weights`` excludes
     placement phantom rows from server-gradient averaging and syncs.
-    Returns ``run(stacked_clients,
-    server, c_opt, s_opt, batches[E,C,NB,...], b_idx, key_idx[E,steps],
-    base_key) -> (..., [E, steps, C] losses)``."""
+
+    Unsharded, the run gathers its batches on the device from
+    ``pack_run_index``'s output: ``run(stacked_clients, server, c_opt,
+    s_opt, data, idx[E,C,NB,B], b_idx, key_idx[E,steps], base_key)``
+    concatenates each key's hospital arrays in ``data`` once and each
+    step takes its ``[C, B, ...]`` rows ``idx[e, c, b_idx[s, c]]``.  With
+    an enabled ``placement`` each device holds its own hospitals' rows, so
+    the run takes ``pack_run``'s grid: ``run(stacked_clients, server,
+    c_opt, s_opt, batches[E,C,NB,...], b_idx, key_idx, base_key)``.
+    Both return ``(..., [E, steps, C] losses)``."""
     epoch = _sflv3_epoch_body(adapter, opt_client, opt_server, n_clients,
                               transport, privacy, client_weights,
                               placement, telemetry)
@@ -698,11 +764,15 @@ def make_sflv3_run(adapter: SplitAdapter, opt_client: O.Optimizer,
     sync_w = (None if client_weights is None
               else jnp.asarray(client_weights, jnp.float32))
 
-    def sflv3_run(stacked_clients, server, c_opt, s_opt, batches, b_idx,
-                  key_idx, base_key):
+    def rounds(state, xs, b_idx, base_key, rows=None):
+        """The scan over epochs; ``xs`` is ``(per-epoch batch grids or
+        row ids, key_idx)``, and ``rows`` the concatenated data."""
         def round_body(carry, xs):
             b_e, ki_e = xs
-            out = epoch(*carry, b_e, b_idx, ki_e, base_key)
+            if rows is None:
+                out = epoch(*carry, b_e, b_idx, ki_e, base_key)
+            else:
+                out = epoch(*carry, rows, b_idx, ki_e, base_key, b_e)
             sc, sp, co, so = out[0], out[1], out[2], out[3]
             ys = (out[4], out[5]) if observed else out[4]
             if sync_clients:
@@ -710,10 +780,20 @@ def make_sflv3_run(adapter: SplitAdapter, opt_client: O.Optimizer,
                     sc = _mean_sync(sc, sync_w)
             return (sc, sp, co, so), ys
 
-        carry, ys = jax.lax.scan(
-            round_body, (stacked_clients, server, c_opt, s_opt),
-            (batches, key_idx))
+        carry, ys = jax.lax.scan(round_body, state, xs)
         return (*carry, *ys) if observed else (*carry, ys)
+
+    if placement is not None and placement.enabled:
+        def sflv3_run(stacked_clients, server, c_opt, s_opt, batches,
+                      b_idx, key_idx, base_key):
+            return rounds((stacked_clients, server, c_opt, s_opt),
+                          (batches, key_idx), b_idx, base_key)
+    else:
+        def sflv3_run(stacked_clients, server, c_opt, s_opt, data, idx,
+                      b_idx, key_idx, base_key):
+            rows = {k: jnp.concatenate(v) for k, v in data.items()}
+            return rounds((stacked_clients, server, c_opt, s_opt),
+                          (idx, key_idx), b_idx, base_key, rows)
 
     return _donating_jit(sflv3_run, donate_argnums=(0, 1, 2, 3, 4))
 

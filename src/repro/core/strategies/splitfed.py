@@ -212,7 +212,9 @@ class SplitFedV3(SplitLearning):
         losses = self._wait(losses)
         with self._span("account"):
             flat = losses[:, :self.n_clients].reshape(-1).tolist()
-            self._account_v3(packed, batch_size)
+            self._account_v3(
+                {k: v[0, 0] for k, v in packed.batches.items()}, packed,
+                batch_size)
             self._end_of_epoch(state)
             log = EpochLog(flat, steps,
                            client_steps=[steps] * self.n_clients)
@@ -222,12 +224,12 @@ class SplitFedV3(SplitLearning):
                     {k: np.asarray(v) for k, v in out[5].items()})
         return state, log
 
-    def _account_v3(self, packed, batch_size, n_epochs=1):
+    def _account_v3(self, example, packed, batch_size, n_epochs=1):
         """Analytic accounting: every client is touched every synchronous
         step (wrap-around resampling included), so the per-epoch count is
-        simply ``steps = nb_max`` for DP and transport alike."""
+        simply ``steps = nb_max`` for DP and transport alike.  ``example``
+        is hospital 0's first batch."""
         steps = packed.nb_max
-        example = {k: v[0, 0] for k, v in packed.batches.items()}
         for c in range(self.n_clients):
             self._dp_account(c, packed.n_samples[c], batch_size,
                              count=steps * n_epochs)
@@ -249,12 +251,31 @@ class SplitFedV3(SplitLearning):
         tel = self._tel
         place = self.placement
         with self._span("pack") as sp:
-            batches, packed = ENG.pack_run(client_data, batch_size, rng,
-                                           n_epochs, True,
-                                           pad_clients=place.n_pad,
-                                           span=self._span)
-            self._pack_span(sp, batches, n_epochs * packed.mask.size,
-                            n_epochs * sum(packed.n_batches))
+            if place.enabled:
+                # each device holds its own hospitals' rows: the host packs
+                # the batch grid, placed hospital-sharded below
+                grid, packed = ENG.pack_run(client_data, batch_size, rng,
+                                            n_epochs, True,
+                                            pad_clients=place.n_pad,
+                                            span=self._span)
+                inputs = (grid,)
+                example = {k: v[0, 0, 0] for k, v in grid.items()}
+                self._pack_span(sp, grid, n_epochs * packed.mask.size,
+                                n_epochs * sum(packed.n_batches))
+                del grid
+            else:
+                # the program gathers every batch from the hospitals' own
+                # arrays; hospital 0's rows come first, so its first batch
+                # is idx[0, 0, 0] of its own array
+                data, idx, packed = ENG.pack_run_index(
+                    client_data, batch_size, rng, n_epochs, True,
+                    pad_clients=place.n_pad, span=self._span)
+                inputs = (data, idx)
+                example = {k: v[0][idx[0, 0, 0]] for k, v in data.items()}
+                slots = n_epochs * packed.mask.size
+                self._pack_span(sp, {**data, "index": idx}, slots,
+                                n_epochs * sum(packed.n_batches),
+                                device_gather=slots)
         self._check_batches(packed.n_batches[:self.n_clients], batch_size)
         steps = packed.nb_max
         if tel is None:
@@ -284,7 +305,7 @@ class SplitFedV3(SplitLearning):
             else np.zeros((steps,), np.uint32) for _ in range(n_epochs)])
         args = (place.put(state["stacked_clients"]), state["server"],
                 place.put(state["c_opt"]), state["s_opt"],
-                place.put(batches, axis=1), place.put(b_idx, axis=1),
+                *place.put(inputs, axis=1), place.put(b_idx, axis=1),
                 key_idx, self._privacy_base_key())
         out = self._enqueue(run_fn, args)
         (state["stacked_clients"], state["server"], state["c_opt"],
@@ -304,10 +325,10 @@ class SplitFedV3(SplitLearning):
                     self.n_clients)
                 for log, r in zip(logs, rounds):
                     log.telemetry = r
-            self._account_v3(packed, batch_size, n_epochs)
+            self._account_v3(example, packed, batch_size, n_epochs)
             # the run's host batches and donated inputs are freed here,
             # inside "account", not in the frame's teardown after it
-            del args, batches, packed, out
+            del args, inputs, example, packed, out
         return state, logs
 
     def _run_participation(self, state, client_data, rng, batch_size,

@@ -210,6 +210,69 @@ def test_run_parity_cut_noise(tiny_setup):
     _assert_run_parity("sl_ac", clients, adapter, privacy=CUT, epochs=2)
 
 
+def _grid_run(st, state, data, rng, batch, epochs):
+    """SFLv3/v1 whole run on ``pack_run``'s host-packed batch grid: the
+    unchanged synchronous-step body scanned over epochs, each step
+    indexing the grid — the layout the device gather replaces."""
+    from repro.core.aggregate import mean_sync
+    from repro.core.strategies.engine import _sflv3_epoch_body, pack_run
+    batches, packed = pack_run(data, batch, rng, epochs)
+    steps = packed.nb_max
+    epoch = _sflv3_epoch_body(st.adapter, st._opt_c, st._opt_s,
+                              st.n_clients, st.transport, st.privacy)
+    b_idx = np.stack([[s % nb for nb in packed.n_batches]
+                      for s in range(steps)]).astype(np.int32)
+    key_idx = np.stack([
+        st._take_key_indices(steps) if st._keyed
+        else np.zeros((steps,), np.uint32) for _ in range(epochs)])
+
+    def run(carry, batches, key_idx, base_key):
+        def round_body(carry, xs):
+            b_e, ki_e = xs
+            out = epoch(*carry, b_e, b_idx, ki_e, base_key)
+            sc = mean_sync(out[0]) if st._sync_stacked else out[0]
+            return (sc, *out[1:4]), out[4]
+        return jax.lax.scan(round_body, carry, (batches, key_idx))
+
+    carry = (state["stacked_clients"], state["server"], state["c_opt"],
+             state["s_opt"])
+    return jax.jit(run)(carry, batches, key_idx, st._privacy_base_key())
+
+
+@pytest.mark.parametrize("privacy", [None, DP, CUT], ids=["plain", "dp",
+                                                           "cut_noise"])
+@pytest.mark.parametrize("method", ["sflv3_ac", "sflv1_ac"])
+def test_device_gather_matches_the_packed_grid(method, privacy, tiny_setup):
+    """The unsharded SFLv3/v1 run gathers its batches on the device from
+    the hospitals' arrays and an index grid: same losses and state, bit
+    for bit, as the host-packed grid, and the same host rng draws."""
+    clients, adapter = tiny_setup
+    data = [c.train for c in clients]
+
+    def strategy():
+        st = make_strategy(method, adapter, lambda: O.adam(1e-3),
+                           len(clients), privacy=privacy)
+        return st, st.setup(jax.random.key(0))
+
+    st, state = strategy()
+    rng = np.random.default_rng(0)
+    state, logs = st.run(state, data, rng, 4, 2)
+    _, args = st._last_run_invocation        # the hospitals' own arrays
+    assert [a.shape[0] for a in args[4]["label"]] == [
+        len(d["label"]) for d in data]
+    ref, ref_state = strategy()
+    ref_rng = np.random.default_rng(0)
+    carry, losses = _grid_run(ref, ref_state, data, ref_rng, 4, 2)
+    assert rng.bit_generator.state == ref_rng.bit_generator.state
+    assert [lg.losses for lg in logs] == [
+        np.asarray(l).reshape(-1).tolist() for l in losses]
+    got = (state["stacked_clients"], state["server"], state["c_opt"],
+           state["s_opt"])
+    assert jax.tree.structure(got) == jax.tree.structure(carry)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(carry)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.parametrize("method", RUN_METHODS)
 def test_run_is_one_program(method, tiny_setup):
     """A 3-epoch compiled run is ONE XLA dispatch: the whole-run program
@@ -230,6 +293,39 @@ def test_run_is_one_program(method, tiny_setup):
     assert st._run_calls == 2
     if hasattr(run_fn, "_cache_size"):
         assert run_fn._cache_size() == 1
+
+
+def test_abstract_args_copies_nothing_to_the_device(monkeypatch):
+    """The stash's skeleton reads a host leaf's shape and dtype (as jit
+    gives it) without a device copy; a committed array keeps its
+    sharding."""
+    import jax.numpy as jnp
+    from repro.core.strategies.engine import abstract_args
+
+    def no_copy(*a, **k):
+        raise AssertionError("abstract_args copied a leaf to the device")
+
+    host = {"image": [np.zeros((5, 8, 8, 1), np.float32),
+                      np.zeros((3, 8, 8, 1), np.float32)],
+            "idx": np.zeros((2, 3, 4), np.int32),
+            "wide": np.zeros((2,), np.int64), "scalar": 1.5}
+    committed = jax.device_put(np.ones((3,), np.float32), jax.devices()[0])
+    n_live = len(jax.live_arrays())
+    with monkeypatch.context() as m:
+        for mod, name in ((jnp, "asarray"), (jnp, "array"),
+                          (jax, "device_put")):
+            m.setattr(mod, name, no_copy)
+        got = abstract_args((host, committed))
+    assert len(jax.live_arrays()) == n_live
+    skel, placed = got
+    assert [(s.shape, s.dtype) for s in skel["image"]] == [
+        ((5, 8, 8, 1), np.float32), ((3, 8, 8, 1), np.float32)]
+    assert (skel["idx"].shape, skel["idx"].dtype) == ((2, 3, 4), np.int32)
+    assert skel["wide"].dtype == jnp.asarray(host["wide"]).dtype
+    assert (skel["scalar"].shape, skel["scalar"].dtype) == ((), np.float32)
+    assert all(s.sharding is None for s in jax.tree.leaves(skel))
+    assert placed.sharding == committed.sharding
+    assert placed.shape == (3,) and placed.dtype == np.float32
 
 
 def test_run_secagg_falls_back_to_per_round(tiny_setup):
@@ -314,6 +410,37 @@ def test_pack_epoch_matches_np_batches():
     assert kept.n_batches == [4, 2]
     assert kept.ex_weights[0, 3].tolist() == [1.0, 0.0, 0.0]
     assert kept.step_examples[0] == [3, 3, 3, 1]
+
+
+@pytest.mark.parametrize("drop_remainder", [True, False])
+def test_pack_run_index_matches_pack_run(drop_remainder):
+    """Gathering the index grid from the hospitals' rows laid end to end
+    gives ``pack_run``'s grid wherever it holds data, from the same rng
+    draws, with the same meta; the arrays are the caller's own."""
+    from repro.core.strategies.engine import pack_run, pack_run_index
+    data = [{"x": np.arange(n, dtype=np.float32)[:, None] + 100 * c,
+             "label": np.arange(n) + 100 * c}
+            for c, n in enumerate([10, 5, 7])]
+    rng_a, rng_b = np.random.default_rng(3), np.random.default_rng(3)
+    grid, meta = pack_run(data, 3, rng_a, 2, drop_remainder, pad_clients=1)
+    got, idx, meta_i = pack_run_index(data, 3, rng_b, 2, drop_remainder,
+                                      pad_clients=1)
+    assert rng_a.bit_generator.state == rng_b.bit_generator.state
+    assert idx.dtype == np.int32 and idx.shape == grid["x"].shape[:4]
+    assert all(a is d[k] for k in got for a, d in zip(got[k], data))
+    for k in ("n_batches", "step_examples", "n_samples", "batch_size"):
+        assert getattr(meta_i, k) == getattr(meta, k)
+    np.testing.assert_array_equal(meta_i.mask, meta.mask)
+    if drop_remainder:
+        assert meta_i.ex_weights is None
+        real = np.broadcast_to(meta.mask[None, :, :, None], idx.shape)
+    else:
+        np.testing.assert_array_equal(meta_i.ex_weights, meta.ex_weights)
+        real = np.broadcast_to(meta.ex_weights[None] > 0, idx.shape)
+    for k in data[0]:
+        gathered = np.concatenate(got[k])[idx]
+        np.testing.assert_array_equal(gathered[real], grid[k][real])
+    assert not idx[~real].any()                   # padding points at row 0
 
 
 def test_schedule_array_matches_schedules():

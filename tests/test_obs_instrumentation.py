@@ -6,7 +6,9 @@ and the compile log.
     a segment, ``cut`` or ``update`` scope, backward included, and the
     scopes leave params and losses bit-identical;
   * the spans of one compiled run tile it: pack, enqueue, wait, account;
-  * the ``pack`` span's byte counters are the packed arrays' ``nbytes``;
+  * the ``pack`` span's byte counters are the ``nbytes`` of what the run
+    hands the program: the packed grid, or for SFLv3 the hospitals'
+    arrays and the index grid the program gathers from;
   * a profiler trace holds the spans as prefixed annotations with stats;
   * the compile log names each compile by function, and logs nothing
     when a compiled program runs again at the same shapes.
@@ -131,33 +133,69 @@ def test_run_spans_tile_the_run(tiny, method, k):
     assert gathers and all(e["args"]["parent"] == "pack" for e in gathers)
 
 
-def test_pack_counters_are_the_packed_bytes(tiny, monkeypatch):
+@pytest.mark.parametrize("method", ["sflv3_ac", "sflv2_ac"])
+def test_pack_counters_are_the_packed_bytes(tiny, method, monkeypatch):
+    """An unsharded SFLv3 run hands the program the hospitals' arrays and
+    an index grid (``pack_run_index``) and gathers every batch slot on the
+    device; a strategy that still packs (SFLv2) counts ``pack_run``'s grid
+    and gathers none."""
     from repro.core.strategies import engine
-    seen = []
     pack_run = engine.pack_run
+    seen = {}
 
-    def spy(*a, **k):
-        out = pack_run(*a, **k)
-        seen.append(out)
-        return out
+    def spy(name):
+        fn = getattr(engine, name)
 
-    monkeypatch.setattr(engine, "pack_run", spy)
+        def wrapped(*a, **k):
+            out = fn(*a, **k)
+            seen.setdefault(name, out)
+            return out
+        return wrapped
+
+    for name in ("pack_run", "pack_run_index"):
+        monkeypatch.setattr(engine, name, spy(name))
     tr = Tracer()
-    _, _, logs = _train(tiny, "sflv3_ac", tracer=tr)
-    batches, packed = seen[0]
+    _train(tiny, method, tracer=tr)
     pack = tr.find("pack")["args"]
-    assert {k: pack[f"bytes_{k}"] for k in batches} == {
-        k: v.nbytes for k, v in batches.items()}
-    assert set(batches) == {"image", "label", "mask"}
-    e, c, nb = batches["label"].shape[:3]
-    assert pack["batch_slots"] == e * c * nb
-    assert pack["real_batches"] == e * sum(packed.n_batches) < e * c * nb
     enqueue = tr.find("enqueue")["args"]
-    assert enqueue["program"] == "sflv3_run"
-    assert enqueue["bytes_host"] >= sum(v.nbytes for v in batches.values())
+    children = [e["name"] for e in tr.events
+                if e["args"].get("parent") == "pack"]
+    if method == "sflv3_ac":
+        assert set(seen) == {"pack_run_index"}
+        data, idx, packed = seen["pack_run_index"]
+        assert set(data) == {"image", "label", "mask"}
+        assert {k: pack[f"bytes_{k}"] for k in data} == {
+            k: sum(a.nbytes for a in v) for k, v in data.items()}
+        assert pack["bytes_index"] == idx.nbytes
+        assert children == ["gather"]                    # nothing stacked
+        e, c, nb = idx.shape[:3]
+        assert pack["device_gather"] == pack["batch_slots"] == e * c * nb
+        # the grid the host packed for the same run before
+        old_grid = sum(a.nbytes for a in pack_run(
+            tiny[0], BATCH, np.random.default_rng(0), EPOCHS)[0].values())
+        assert enqueue["program"] == "sflv3_run"
+        assert enqueue["bytes_host"] >= (sum(pack[f"bytes_{k}"] for k in data)
+                                         + idx.nbytes)
+        assert enqueue["bytes_host"] < old_grid
+        # every step trains every hospital's full batch: the run's images
+        assert tr.find("run")["args"]["images"] == EPOCHS * nb * N * BATCH
+    else:
+        assert set(seen) == {"pack_run"}
+        batches, packed = seen["pack_run"]
+        assert {k: pack[f"bytes_{k}"] for k in batches} == {
+            k: v.nbytes for k, v in batches.items()}
+        assert set(batches) == {"image", "label", "mask"}
+        e, c, nb = batches["label"].shape[:3]
+        assert pack["batch_slots"] == e * c * nb
+        assert pack["device_gather"] == 0
+        assert children == ["gather"] * EPOCHS + ["stack"]
+        assert enqueue["program"] == "interleaved_run"
+        assert enqueue["bytes_host"] >= sum(v.nbytes
+                                            for v in batches.values())
+        assert tr.find("run")["args"]["images"] == (
+            EPOCHS * sum(packed.n_batches) * BATCH)
+    assert pack["real_batches"] == e * sum(packed.n_batches) < e * c * nb
     assert enqueue["bytes_in"] > enqueue["bytes_host"]
-    # every step trains every hospital's full batch: the run's images
-    assert tr.find("run")["args"]["images"] == EPOCHS * nb * N * BATCH
 
 
 def test_profiler_trace_holds_the_annotated_spans(tmp_path):
