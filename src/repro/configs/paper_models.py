@@ -11,7 +11,8 @@ DENSENET121_PAPER = DenseNetConfig(
 
 UNET_PAPER = UNetConfig(
     name="unet-xception-paper", widths=(64, 128, 256, 512, 728), in_ch=1,
-    n_classes=1, cut_layer=6)                # paper: first 6 layers at client
+    n_classes=1, cut_layer=6,                # paper: first 6 layers at client
+    remat=True)                              # batch 4 at 768^2 on 16 GB
 
 # reduced variants for the CPU reproduction run (orderings, not absolutes)
 DENSENET_MINI = DenseNetConfig(

@@ -14,6 +14,8 @@ semantics of the shared server segment.
 
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
 
 from repro.core.schedule import SCHEDULES, schedule_array
@@ -41,6 +43,23 @@ class SplitLearning(Strategy):
             if self.observe is not None:
                 raise ValueError("participation with observe is not "
                                  "supported for the split family")
+
+    @contextlib.contextmanager
+    def _account_span(self):
+        """The ``account`` span of a compiled run or epoch.  With a
+        transport it gets the run's wire counters from the transport's
+        accounting inside it: ``wire_bytes`` and ``wire_bytes_raw`` (both
+        legs, on the wire and as float32) and ``cut_arrays`` (the arrays
+        that cross the cut per step)."""
+        t = self.transport
+        with self._span("account") as sp:
+            if t is not None:
+                wire, raw = t.bytes_on_wire, t.bytes_raw
+            yield sp
+            if sp is not None and t is not None:
+                sp.set(wire_bytes=int(t.bytes_on_wire - wire),
+                       wire_bytes_raw=int(t.bytes_raw - raw),
+                       cut_arrays=t.cut_arrays(self.adapter))
 
     def _client_tree(self, params):
         t = {"front": params["front"]}
@@ -181,7 +200,7 @@ class SplitLearning(Strategy):
         (state["stacked_clients"], state["server"],
          state["stacked_c_opts"], state["s_opt"], losses) = out[:5]
         losses = self._wait(losses)
-        with self._span("account"):
+        with self._account_span():
             flat, loss_w = ENG.scheduled_log(losses, sched, packed)
             # the interleave program's output sharding is compiler-chosen:
             # re-place so between-epoch state is always on the hosp mesh
@@ -248,7 +267,7 @@ class SplitLearning(Strategy):
          state["stacked_c_opts"], state["s_opt"], losses) = out[:5]
         self._run_calls = getattr(self, "_run_calls", 0) + 1
         losses = self._wait(losses)
-        with self._span("account"):
+        with self._account_span():
             state["stacked_clients"] = place.put(state["stacked_clients"])
             state["stacked_c_opts"] = place.put(state["stacked_c_opts"])
             logs = []
@@ -329,7 +348,7 @@ class SplitLearning(Strategy):
          state["stacked_c_opts"], state["s_opt"], losses) = out[:5]
         self._run_calls = getattr(self, "_run_calls", 0) + 1
         losses = self._wait(losses)
-        with self._span("account"):
+        with self._account_span():
             logs = self._account_participation(
                 losses, rounds, pack, part, batch_size, n_epochs, batches)
             # the run's host batches and donated inputs are freed here,

@@ -210,7 +210,7 @@ class SplitFedV3(SplitLearning):
         (state["stacked_clients"], state["server"], state["c_opt"],
          state["s_opt"], losses) = out[:5]
         losses = self._wait(losses)
-        with self._span("account"):
+        with self._account_span():
             flat = losses[:, :self.n_clients].reshape(-1).tolist()
             self._account_v3(
                 {k: v[0, 0] for k, v in packed.batches.items()}, packed,
@@ -312,7 +312,7 @@ class SplitFedV3(SplitLearning):
          state["s_opt"], losses) = out[:5]
         self._run_calls = getattr(self, "_run_calls", 0) + 1
         losses = self._wait(losses)
-        with self._span("account"):
+        with self._account_span():
             logs = [EpochLog(
                 losses[e, :, :self.n_clients].reshape(-1).tolist(), steps,
                 client_steps=[steps] * self.n_clients)
@@ -392,7 +392,7 @@ class SplitFedV3(SplitLearning):
          state["s_opt"], losses) = out[:5]
         self._run_calls = getattr(self, "_run_calls", 0) + 1
         losses = self._wait(losses)
-        with self._span("account"):
+        with self._account_span():
             logs = self._account_participation(
                 losses, pack, part, real_steps, batch_size, n_epochs,
                 batches)

@@ -8,7 +8,10 @@ client average under ``update``.  The scopes are HLO metadata only: an
 instruction's ``metadata={op_name="jit(sflv3_run)/while/body/.../
 transpose(jvp(front))/conv_general_dilated"}`` names its scope, and
 autodiff's ``jvp(...)``/``transpose(...)`` wrappers are stripped, so the
-backward pass counts to its segment.
+backward pass counts to its segment.  What a ``jax.checkpoint`` recomputes
+in the backward pass (JAX names it ``rematted_computation``) is scope
+``remat`` whatever segment holds it, so the cost of rematerialisation
+reads apart from the forward pass it repeats.
 
 ``op_scopes`` maps a compiled module's instruction names (what a device
 trace names its operations) to their scope; ``traced_scope_seconds``
@@ -24,7 +27,9 @@ import re
 SEGMENTS = ("front", "middle", "tail")
 CUT = "cut"
 UPDATE = "update"
-SCOPES = SEGMENTS + (CUT, UPDATE)
+REMAT = "remat"
+SCOPES = SEGMENTS + (CUT, UPDATE, REMAT)
+_REMATTED = "rematted_computation"     # JAX's name for the recomputation
 
 _INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s.*?'
                     r'metadata=\{[^}]*?op_name="([^"]*)"')
@@ -41,8 +46,12 @@ def _unwrap(part: str) -> str:
 
 
 def scope_of(op_name: str) -> str | None:
-    """The innermost of ``SCOPES`` in an ``op_name`` path, else None."""
-    for part in reversed(op_name.split("/")):
+    """``remat`` for a checkpoint's recomputation, else the innermost of
+    ``SCOPES`` in an ``op_name`` path, else None."""
+    parts = op_name.split("/")
+    if _REMATTED in parts:
+        return REMAT
+    for part in reversed(parts):
         base = _unwrap(part)
         if base in SCOPES:
             return base
@@ -105,5 +114,6 @@ def traced_scope_seconds(ops: dict, t0: float, t1: float) -> dict | None:
     return scope_seconds(ops, scopes)
 
 
-__all__ = ["SEGMENTS", "CUT", "UPDATE", "SCOPES", "scope_of", "op_scopes",
-           "program_op_scopes", "scope_seconds", "traced_scope_seconds"]
+__all__ = ["SEGMENTS", "CUT", "UPDATE", "REMAT", "SCOPES", "scope_of",
+           "op_scopes", "program_op_scopes", "scope_seconds",
+           "traced_scope_seconds"]

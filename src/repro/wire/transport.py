@@ -119,12 +119,19 @@ class Transport:
             wire = sum(tree_wire_bytes(self.codec, t)
                        for t in specs.values())
             raw = sum(leaf_bytes(t) for t in specs.values())
-            self._cache[key] = (wire, raw)
-        wire, raw = self._cache[key]
+            arrays = sum(len(jax.tree.leaves(t)) for t in specs.values())
+            self._cache[key] = (wire, raw, arrays)
+        wire, raw, _ = self._cache[key]
         legs = 2 if train else 1           # train: + gradient leg back
         self.bytes_on_wire += count * legs * wire
         self.bytes_raw += count * legs * raw
         self.steps += count
+
+    def cut_arrays(self, adapter) -> int:
+        """Arrays that cross ``adapter``'s boundaries per step, as the
+        accounting so far found them (0 before any step was accounted)."""
+        return max((v[2] for k, v in self._cache.items()
+                    if k[0] == "bytes" and k[1] == adapter), default=0)
 
     def record_epoch(self, adapter, example_batch: dict, kind: str,
                      schedule: str, n_batches, client_set=None) -> None:

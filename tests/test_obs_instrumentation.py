@@ -9,6 +9,8 @@ and the compile log.
   * the ``pack`` span's byte counters are the ``nbytes`` of what the run
     hands the program: the packed grid, or for SFLv3 the hospitals'
     arrays and the index grid the program gathers from;
+  * the ``account`` span's wire counters are the transport's metering of
+    the run, and what ``jax.checkpoint`` recomputes is scope ``remat``;
   * a profiler trace holds the spans as prefixed annotations with stats;
   * the compile log names each compile by function, and logs nothing
     when a compiled program runs again at the same shapes.
@@ -29,7 +31,8 @@ from repro.core.participation import Participation
 from repro.core.partition import cnn_adapter
 from repro.core.strategies import make_strategy
 from repro.data.synthetic import make_cxr_clients
-from repro.models.cnn import DenseNetConfig, build_densenet
+from repro.models.cnn import (DenseNetConfig, UNetConfig, build_densenet,
+                              build_unet)
 from repro.obs import compile_log, scopes
 from repro.obs.trace import ANNOTATION_PREFIX, Tracer, recent_spans
 from repro.wire import Transport
@@ -198,6 +201,72 @@ def test_pack_counters_are_the_packed_bytes(tiny, method, monkeypatch):
     assert enqueue["bytes_in"] > enqueue["bytes_host"]
 
 
+def _tiny_unet(remat=True, widths=(4, 8, 8, 16, 16)):
+    """A U-Net whose cut carries the bottleneck and every skip."""
+    return cnn_adapter(build_unet(UNetConfig(
+        widths=widths, cut_layer=len(widths) + 1, remat=remat)))
+
+
+WIRE = [(m, a) for m in ("sl_am", "sflv3_ac") for a in ("unet", "densenet")]
+
+
+@pytest.mark.parametrize("method,arch", WIRE,
+                         ids=[f"{m}-{a}" for m, a in WIRE])
+def test_account_counters_are_the_runs_wire_traffic(tiny, method, arch):
+    """Each run's ``account`` span carries the bytes the transport metered
+    for that run, on the wire and raw, and the arrays crossing the cut:
+    the U-Net's bottleneck and four skips, the DenseNet's one array."""
+    data, adapter = tiny
+    if arch == "unet":
+        adapter = _tiny_unet()
+    tr = Tracer()
+    st, _, _ = _train((data, adapter), method, tracer=tr, runs=2)
+    accounts = [e["args"] for e in tr.events if e["name"] == "account"]
+    assert len(accounts) == 2
+    t = st.transport
+    assert sum(a["wire_bytes"] for a in accounts) == t.bytes_on_wire > 0
+    assert sum(a["wire_bytes_raw"] for a in accounts) == t.bytes_raw
+    assert accounts[0]["wire_bytes"] == accounts[1]["wire_bytes"]
+    assert accounts[0]["wire_bytes_raw"] > accounts[0]["wire_bytes"]
+    assert {a["cut_arrays"] for a in accounts} == {
+        5 if arch == "unet" else 1}
+    # a run without a transport sets none
+    st0 = make_strategy(method, adapter, lambda: O.adam(1e-3), N)
+    tr0 = st0.attach_tracer(Tracer())
+    st0.run(st0.setup(jax.random.key(0)), data, np.random.default_rng(0),
+            BATCH, 1)
+    assert "wire_bytes" not in tr0.find("account")["args"]
+
+
+def test_remat_matches_the_plain_model_and_names_its_recomputation(tiny):
+    """A compiled SL run of the U-Net with its blocks checkpointed (one
+    image at a time) against the plain model: losses and params within
+    float32 round-off, and operations in the ``remat`` scope only with
+    checkpointing on.  SGD, no int8 link and three levels (a 4x4
+    bottleneck), so that the gradients' round-off stays round-off: Adam
+    moves a coordinate whose gradient is round-off by about its learning
+    rate, the link can round an activation to the next int8 step, and
+    group norm over a 1x1 bottleneck divides round-off by round-off.
+    Tolerance 1e-5 relative: the batch's gradient is summed image by
+    image, in another order."""
+    data, _ = tiny
+    runs = {}
+    for remat in (True, False):
+        st = make_strategy("sl_am", _tiny_unet(remat, (4, 8, 8)),
+                           lambda: O.sgd(0.01), N)
+        state, logs = st.run(st.setup(jax.random.key(0)), data,
+                             np.random.default_rng(0), BATCH, EPOCHS)
+        named = scopes.op_scopes(_compiled_text(st))
+        runs[remat] = (state, [x for lg in logs for x in lg.losses],
+                       sum(s == "remat" for s in named.values()))
+    assert runs[True][2] > 0 and runs[False][2] == 0
+    np.testing.assert_allclose(runs[True][1], runs[False][1], rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(runs[True][0]),
+                    jax.tree.leaves(runs[False][0])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-6)
+
+
 def test_profiler_trace_holds_the_annotated_spans(tmp_path):
     from jax.profiler import ProfileData
     tr = Tracer()
@@ -264,6 +333,10 @@ def test_scope_of_strips_autodiff_and_vmap_wrappers():
     assert scopes.scope_of("jit(f)/jvp(vmap(cut))/jit(fused)/mul") == "cut"
     assert scopes.scope_of("jit(f)/update/middle/add") == "middle"
     assert scopes.scope_of("jit(f)/while/body/add") is None
+    # a checkpoint's recomputation is remat, whatever segment holds it
+    assert scopes.scope_of("jit(f)/transpose(jvp(middle))/jvp(middle)/"
+                           "checkpoint/rematted_computation/conv") == "remat"
+    assert scopes.scope_of("jit(f)/jvp(middle)/checkpoint/conv") == "middle"
     text = "\n".join([
         '  %fusion.7 = f32[2]{0} fusion(%a), kind=kLoop, calls=%c, '
         'metadata={op_name="jit(r)/transpose(jvp(middle))/mul"}',
@@ -276,7 +349,7 @@ def test_scope_of_strips_autodiff_and_vmap_wrappers():
     assert scopes.scope_seconds({"fusion.7": 2.0, "copy.1": 1.0,
                                  "add.3": 0.5}, scopes.op_scopes(text)) == {
         "front": 0.0, "middle": 2.0, "tail": 0.0, "cut": 0.0,
-        "update": 1.0, None: 0.5}
+        "update": 1.0, "remat": 0.0, None: 0.5}
 
 
 def test_span_log_keeps_finished_spans_on_the_host_clock():

@@ -25,11 +25,14 @@ from repro.kernels.cut_fuse.cut_fuse import (noise_roundtrip_pallas,
                                              roundtrip_pallas)
 from repro.kernels.dp_clip.dp_clip import scale_accum_pallas, sqnorms_pallas
 
-# (rows, features) of cut-layer activations at batch 8, flattened as the
-# ops layer hands them to the kernels: DenseNet-121 @224 after unit 4
-# (8 x 56 x 56 rows, 160 ch) and the paper U-Net's 728-channel activation
-# (8 x 24 x 24 rows)
-CUT_SHAPES = {"densenet": (25088, 160), "unet": (4608, 728)}
+# (rows, features) of cut-layer activations, flattened as the ops layer
+# hands them to the kernels: DenseNet-121 @224 after unit 4 at batch 8
+# (8 x 56 x 56 rows, 160 ch), a 728-channel activation of 8 x 24 x 24
+# rows, and the paper U-Net @768 at batch 4: its bottleneck (4 x 48 x 48
+# rows, 728 ch) and its largest skip (4 x 768 x 768 rows, 64 ch)
+CUT_SHAPES = {"densenet": (25088, 160), "unet": (4608, 728),
+              "unet768_bottleneck": (9216, 728),
+              "unet768_skip64": (2359296, 64)}
 DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
 DP_BATCH = 8
 
