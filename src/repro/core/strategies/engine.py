@@ -12,11 +12,12 @@ into a single XLA program instead:
     ``[n_clients, n_batches]`` validity mask; uneven hospital sizes become
     masked (no-op) scan steps and, with ``drop_remainder=False``, the final
     short batch becomes per-example weights instead of a ragged shape.
-    An unsharded SFLv3/v1 whole run takes the **index layout** instead
-    (``pack_run_index``): the hospitals' own arrays and an int32
-    ``[n_epochs, n_clients, n_batches, batch]`` grid of row ids, from
-    which the program gathers each step's batch on the device; the host
-    copies no image bytes.  Every other run takes the packed grid.
+    An unsharded, non-participating SL/SFLv2/SFLv3/v1 whole run takes
+    the **index layout** instead (``pack_run_index``): the hospitals' own
+    arrays and an int32 ``[n_epochs, n_clients, n_batches, batch]`` grid
+    of row ids, from which the program gathers each step's batch on the
+    device; the host copies no image bytes.  Every other run takes the
+    packed grid.
   * **scan over batches, vmap over hospitals** where semantics allow it:
     FL local epochs are independent per hospital, so the per-client
     ``lax.scan`` is wrapped in a ``vmap`` over the stacked hospital axis.
@@ -318,12 +319,18 @@ def _interleaved_epoch_body(adapter: SplitAdapter, opt_client: O.Optimizer,
     observed = telemetry is not None
 
     def epoch(stacked_clients, server, stacked_c_opts, s_opt, batches,
-              ex_w, sched, key_idx, base_key):
+              ex_w, sched, key_idx, base_key, rows=None):
+        # rows ([C, NB, B] global row ids) given: ``batches`` lists each
+        # key's hospital arrays, and each step reads its rows from the
+        # arrays of the hospital it trains
         def body(carry, xs):
             sc, sp, co, so = carry
             cb, ki = xs
             c, b = cb[0], cb[1]
-            batch = jax.tree.map(lambda x: x[c, b], batches)
+            if rows is None:
+                batch = jax.tree.map(lambda x: x[c, b], batches)
+            else:
+                batch = _hospital_rows(batches, rows[c, b], c)
             w = None if ex_w is None else ex_w[c, b]
             out = step(
                 tree_take(sc, c), sp, tree_take(co, c), so, batch,
@@ -338,6 +345,27 @@ def _interleaved_epoch_body(adapter: SplitAdapter, opt_client: O.Optimizer,
         return (*carry, *ys) if observed else (*carry, ys)
 
     return epoch
+
+
+def _hospital_rows(data, rows, c):
+    """Hospital ``c``'s batch, read from its own arrays: ``data[k]`` lists
+    the hospitals' ``k`` arrays and ``rows`` holds global row ids into
+    them laid end to end.  One ``lax.switch`` branch a hospital reads that
+    hospital's arrays alone, so the program holds no concatenated copy of
+    the data.  A hospital without rows is never scheduled; its branch
+    gives zeros."""
+    sizes = [len(a) for a in next(iter(data.values()))]
+    offsets = np.cumsum([0] + sizes[:-1])
+
+    def branch(i):
+        def take(r):
+            if not sizes[i]:
+                return {k: jnp.zeros(r.shape + v[i].shape[1:], v[i].dtype)
+                        for k, v in data.items()}
+            return {k: v[i][r - offsets[i]] for k, v in data.items()}
+        return take
+
+    return jax.lax.switch(c, [branch(i) for i in range(len(sizes))], rows)
 
 
 def make_interleaved_epoch(adapter: SplitAdapter, opt_client: O.Optimizer,
@@ -495,7 +523,7 @@ def _donating_jit(fn, donate_argnums):
     The run carries (params / optimizer state, which the scan returns with
     identical shapes — XLA aliases them in place) and the packed
     ``[E, C, NB, B, ...]`` batch stack, or the hospital arrays an unsharded
-    SFLv3 run gathers from (no aliasable output, but freeing it
+    split-family run gathers from (no aliasable output, but freeing it
     at entry lets the allocator reuse the run's largest buffer as scratch)
     are dead to the caller the moment the run is dispatched: every strategy
     immediately overwrites its state with the outputs.  Donating them cuts
@@ -582,7 +610,9 @@ def pack_run(client_data, batch_size: int, rng, n_epochs: int,
 def pack_run_index(client_data, batch_size: int, rng, n_epochs: int,
                    drop_remainder: bool = True, pad_clients: int = 0,
                    span=_no_span):
-    """``pack_run``'s batches as row indices: ``(data, idx, meta)``.
+    """``pack_run``'s batches as row indices: ``(data, idx, meta)``, what
+    the unsharded SL/SFLv2 (``make_interleaved_run``) and SFLv3/v1
+    (``make_sflv3_run``) whole runs gather their batches from.
 
     ``data[k]`` lists every hospital's ``k`` array as the caller gave it
     (no copy, no padding).  ``idx`` is an int32 ``[n_epochs, n_clients,
@@ -701,16 +731,26 @@ def make_seq_run(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
 def make_interleaved_run(adapter: SplitAdapter, opt_client: O.Optimizer,
                          opt_server: O.Optimizer, transport=None,
                          privacy=None, sync_clients: bool = False,
-                         client_weights=None, telemetry=None):
+                         client_weights=None, placement=None,
+                         telemetry=None):
     """Whole SL/SFLv2 run: scan over epochs around the scanned schedule
     interleave body ``make_interleaved_epoch`` jits.  ``sync_clients``
     folds the SFLv2 end-of-epoch client fed-averaging into the round
     body (``client_weights`` excludes placement phantom rows from it).
     The schedule array is epoch-invariant (batch counts never
     change) and is rescanned each round; per-epoch key indices arrive as
-    ``key_idx[E, steps]``.  Returns ``run(stacked_clients, server,
-    stacked_c_opts, s_opt, batches[E,C,NB,...], ex_w, sched, key_idx,
-    base_key) -> (..., [E, steps] losses)``.
+    ``key_idx[E, steps]``.
+
+    Unsharded, the run gathers its batches on the device from
+    ``pack_run_index``'s output, as an unsharded SFLv3 run does:
+    ``run(stacked_clients, server, stacked_c_opts, s_opt, data,
+    idx[E,C,NB,B], ex_w, sched, key_idx, base_key)``; step ``s`` of epoch
+    ``e`` takes the rows ``idx[e, c, b]`` for the ``(c, b)`` that
+    ``sched[s]`` names, from hospital ``c``'s own arrays in ``data``.
+    With an enabled ``placement`` the run takes ``pack_run``'s grid:
+    ``run(stacked_clients, server, stacked_c_opts, s_opt,
+    batches[E,C,NB,...], ex_w, sched, key_idx, base_key)``.  Both return
+    ``(..., [E, steps] losses)``.
     """
     epoch = _interleaved_epoch_body(adapter, opt_client, opt_server,
                                     transport, privacy, telemetry)
@@ -718,11 +758,15 @@ def make_interleaved_run(adapter: SplitAdapter, opt_client: O.Optimizer,
     sync_w = (None if client_weights is None
               else jnp.asarray(client_weights, jnp.float32))
 
-    def interleaved_run(stacked_clients, server, stacked_c_opts, s_opt,
-                        batches, ex_w, sched, key_idx, base_key):
+    def rounds(state, xs, ex_w, sched, base_key, data=None):
+        """The scan over epochs; ``xs`` is ``(per-epoch batch grids or
+        row ids, key_idx)``, and ``data`` the hospitals' arrays."""
         def round_body(carry, xs):
             b_e, ki_e = xs
-            out = epoch(*carry, b_e, ex_w, sched, ki_e, base_key)
+            if data is None:
+                out = epoch(*carry, b_e, ex_w, sched, ki_e, base_key)
+            else:
+                out = epoch(*carry, data, ex_w, sched, ki_e, base_key, b_e)
             sc, sp, co, so = out[0], out[1], out[2], out[3]
             ys = (out[4], out[5]) if observed else out[4]
             if sync_clients:
@@ -730,10 +774,19 @@ def make_interleaved_run(adapter: SplitAdapter, opt_client: O.Optimizer,
                     sc = _mean_sync(sc, sync_w)
             return (sc, sp, co, so), ys
 
-        carry, ys = jax.lax.scan(
-            round_body, (stacked_clients, server, stacked_c_opts, s_opt),
-            (batches, key_idx))
+        carry, ys = jax.lax.scan(round_body, state, xs)
         return (*carry, *ys) if observed else (*carry, ys)
+
+    if placement is not None and placement.enabled:
+        def interleaved_run(stacked_clients, server, stacked_c_opts, s_opt,
+                            batches, ex_w, sched, key_idx, base_key):
+            return rounds((stacked_clients, server, stacked_c_opts, s_opt),
+                          (batches, key_idx), ex_w, sched, base_key)
+    else:
+        def interleaved_run(stacked_clients, server, stacked_c_opts, s_opt,
+                            data, idx, ex_w, sched, key_idx, base_key):
+            return rounds((stacked_clients, server, stacked_c_opts, s_opt),
+                          (idx, key_idx), ex_w, sched, base_key, data)
 
     return _donating_jit(interleaved_run, donate_argnums=(0, 1, 2, 3, 4))
 

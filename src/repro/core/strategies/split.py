@@ -15,6 +15,7 @@ semantics of the shared server segment.
 from __future__ import annotations
 
 import contextlib
+import functools
 
 import numpy as np
 
@@ -206,7 +207,9 @@ class SplitLearning(Strategy):
             # re-place so between-epoch state is always on the hosp mesh
             state["stacked_clients"] = place.put(state["stacked_clients"])
             state["stacked_c_opts"] = place.put(state["stacked_c_opts"])
-            self._account_compiled(packed, batch_size)
+            self._account_compiled(
+                {k: v[0, 0] for k, v in packed.batches.items()}, packed,
+                batch_size)
             self._end_of_epoch(state)
             log = EpochLog(flat, len(flat), weights=loss_w,
                            client_steps=list(
@@ -231,28 +234,46 @@ class SplitLearning(Strategy):
         tel = self._tel
         place = self.placement
         with self._span("pack") as sp:
-            batches, packed = ENG.pack_run(client_data, batch_size, rng,
-                                           n_epochs, self.drop_remainder,
-                                           pad_clients=place.n_pad,
-                                           span=self._span)
-            self._pack_span(sp, batches, n_epochs * packed.mask.size,
-                            n_epochs * sum(packed.n_batches))
+            if place.enabled:
+                # each device holds its own hospitals' rows: the host packs
+                # the batch grid, placed hospital-sharded below
+                grid, packed = ENG.pack_run(client_data, batch_size, rng,
+                                            n_epochs, self.drop_remainder,
+                                            pad_clients=place.n_pad,
+                                            span=self._span)
+                inputs = (grid,)
+                example = {k: v[0, 0, 0] for k, v in grid.items()}
+                self._pack_span(sp, grid, n_epochs * packed.mask.size,
+                                n_epochs * sum(packed.n_batches))
+                del grid
+            else:
+                # the program gathers every batch from the hospitals' own
+                # arrays; the accounting reads only a batch's shape
+                data, idx, packed = ENG.pack_run_index(
+                    client_data, batch_size, rng, n_epochs,
+                    self.drop_remainder, pad_clients=place.n_pad,
+                    span=self._span)
+                inputs = (data, idx)
+                example = {k: np.zeros((batch_size, *v[0].shape[1:]),
+                                       v[0].dtype) for k, v in data.items()}
+                slots = n_epochs * packed.mask.size
+                self._pack_span(sp, {**data, "index": idx}, slots,
+                                n_epochs * sum(packed.n_batches),
+                                device_gather=slots)
         sched = schedule_array(self.schedule, packed.n_batches)
         sync_w = place.client_weights() if place.padded else None
+        build = functools.partial(
+            ENG.make_interleaved_run, self.adapter, self._opt_c,
+            self._opt_s, self.transport, self.privacy,
+            sync_clients=self._sync_stacked, client_weights=sync_w,
+            placement=place)
         if tel is None:
             if not hasattr(self, "_run_c"):
-                self._run_c = ENG.make_interleaved_run(
-                    self.adapter, self._opt_c, self._opt_s, self.transport,
-                    self.privacy, sync_clients=self._sync_stacked,
-                    client_weights=sync_w)
+                self._run_c = build()
             run_fn = self._run_c
         else:
-            run_fn = self._get_obs(
-                "_run_obs_c", tel,
-                lambda: ENG.make_interleaved_run(
-                    self.adapter, self._opt_c, self._opt_s, self.transport,
-                    self.privacy, sync_clients=self._sync_stacked,
-                    client_weights=sync_w, telemetry=tel))
+            run_fn = self._get_obs("_run_obs_c", tel,
+                                   lambda: build(telemetry=tel))
         key_idx = np.stack([
             self._take_key_indices(len(sched)) if self._keyed
             else np.zeros((len(sched),), np.uint32)
@@ -260,7 +281,7 @@ class SplitLearning(Strategy):
         self._ensure_stacked(state)
         args = (state["stacked_clients"], state["server"],
                 state["stacked_c_opts"], state["s_opt"],
-                place.put(batches, axis=1), place.put(packed.ex_weights),
+                *place.put(inputs, axis=1), place.put(packed.ex_weights),
                 sched, key_idx, self._privacy_base_key())
         out = self._enqueue(run_fn, args)
         (state["stacked_clients"], state["server"],
@@ -284,10 +305,10 @@ class SplitLearning(Strategy):
                     sched, self.n_clients)
                 for log, r in zip(logs, rounds):
                     log.telemetry = r
-            self._account_compiled(packed, batch_size, n_epochs)
+            self._account_compiled(example, packed, batch_size, n_epochs)
             # the run's host batches and donated inputs are freed here,
             # inside "account", not in the frame's teardown after it
-            del args, batches, packed, out
+            del args, inputs, example, packed, out
         return state, logs
 
     def _run_participation(self, state, client_data, rng, batch_size,
@@ -398,14 +419,14 @@ class SplitLearning(Strategy):
                 self._record_wire_epoch(example, counts, client_set=ids)
         return logs
 
-    def _account_compiled(self, packed, batch_size, n_epochs=1):
+    def _account_compiled(self, example, packed, batch_size, n_epochs=1):
         """Analytic accounting for the compiled path: the DP accountant
         composes each hospital's step count in one call, and the transport
         meters each step at its TRUE batch shape — full batches in one
         ``count=`` call, a kept remainder batch (``drop_remainder=False``)
         at its short shape, exactly the bytes the stepwise per-step path
-        meters — times ``n_epochs`` for a whole-run program."""
-        example = {k: v[0, 0] for k, v in packed.batches.items()}
+        meters — times ``n_epochs`` for a whole-run program.  ``example``
+        is a full batch; only its shapes are read."""
         for c, nb in enumerate(packed.n_batches):
             if not nb:
                 continue

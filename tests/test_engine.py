@@ -273,6 +273,96 @@ def test_device_gather_matches_the_packed_grid(method, privacy, tiny_setup):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def _interleaved_grid_run(st, state, data, rng, batch, epochs):
+    """SL/SFLv2 whole run on ``pack_run``'s host-packed batch grid: the
+    unchanged schedule-interleave body scanned over epochs, each step
+    indexing the grid — the layout the device gather replaces."""
+    import jax.numpy as jnp
+    from repro.core.aggregate import mean_sync
+    from repro.core.strategies.engine import (_interleaved_epoch_body,
+                                              pack_run)
+    place = st.placement
+    batches, packed = pack_run(data, batch, rng, epochs,
+                               pad_clients=place.n_pad)
+    sched = schedule_array(st.schedule, packed.n_batches)
+    epoch = _interleaved_epoch_body(st.adapter, st._opt_c, st._opt_s,
+                                    st.transport, st.privacy)
+    key_idx = np.stack([
+        st._take_key_indices(len(sched)) if st._keyed
+        else np.zeros((len(sched),), np.uint32) for _ in range(epochs)])
+    w = jnp.asarray(place.client_weights()) if place.padded else None
+
+    def run(carry, batches, key_idx, base_key):
+        def round_body(carry, xs):
+            b_e, ki_e = xs
+            out = epoch(*carry, b_e, None, sched, ki_e, base_key)
+            sc = mean_sync(out[0], w) if st._sync_stacked else out[0]
+            return (sc, *out[1:4]), out[4]
+        return jax.lax.scan(round_body, carry, (batches, key_idx))
+
+    st._ensure_stacked(state)
+    carry = (state["stacked_clients"], state["server"],
+             state["stacked_c_opts"], state["s_opt"])
+    return jax.jit(run)(carry, batches, key_idx, st._privacy_base_key())
+
+
+def _assert_split_gather(method, adapter, data, privacy=None, pad=0,
+                         epochs=2):
+    """An SL/SFLv2 ``Strategy.run`` equals the host-packed grid run bit
+    for bit, from the same host rng draws, and the program received the
+    hospitals' own arrays."""
+    from repro.core.placement import Placement
+
+    def strategy():
+        st = make_strategy(method, adapter, lambda: O.adam(1e-3),
+                           len(data), privacy=privacy)
+        st.placement = Placement(len(data), len(data) + pad)
+        return st, st.setup(jax.random.key(0))
+
+    st, state = strategy()
+    rng = np.random.default_rng(0)
+    state, logs = st.run(state, data, rng, 4, epochs)
+    _, args = st._last_run_invocation        # the hospitals' own arrays
+    assert [a.shape[0] for a in args[4]["label"]] == [
+        len(d["label"]) for d in data]
+    assert args[5].shape[:2] == (epochs, len(data) + pad)
+    ref, ref_state = strategy()
+    ref_rng = np.random.default_rng(0)
+    carry, losses = _interleaved_grid_run(ref, ref_state, data, ref_rng, 4,
+                                          epochs)
+    assert rng.bit_generator.state == ref_rng.bit_generator.state
+    assert [lg.losses for lg in logs] == np.asarray(losses).tolist()
+    got = (state["stacked_clients"], state["server"],
+           state["stacked_c_opts"], state["s_opt"])
+    assert jax.tree.structure(got) == jax.tree.structure(carry)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(carry)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("pad", [0, 1], ids=["real", "phantom"])
+@pytest.mark.parametrize("privacy", [None, DP], ids=["plain", "dp"])
+@pytest.mark.parametrize("method", ["sl_am", "sl_ac", "sflv2_ac"])
+def test_split_device_gather_matches_the_packed_grid(method, privacy, pad,
+                                                     tiny_setup):
+    """The unsharded SL/SFLv2 run gathers each step's batch on the device
+    from its hospital's own arrays and an index grid: same losses and
+    state, bit for bit, as the host-packed grid; ``pad`` phantom
+    hospitals (a placement's padding without a mesh) ride along
+    unscheduled."""
+    clients, adapter = tiny_setup
+    _assert_split_gather(method, adapter, [c.train for c in clients],
+                         privacy, pad)
+
+
+def test_split_device_gather_skips_a_hospital_without_rows(tiny_setup):
+    """A hospital that holds no rows is never scheduled, and its branch
+    of the gather reads nothing."""
+    clients, adapter = tiny_setup
+    data = [c.train for c in clients]
+    data[1] = {k: v[:0] for k, v in data[1].items()}
+    _assert_split_gather("sl_am", adapter, data, epochs=1)
+
+
 @pytest.mark.parametrize("method", RUN_METHODS)
 def test_run_is_one_program(method, tiny_setup):
     """A 3-epoch compiled run is ONE XLA dispatch: the whole-run program

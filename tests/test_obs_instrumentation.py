@@ -7,8 +7,9 @@ and the compile log.
     scopes leave params and losses bit-identical;
   * the spans of one compiled run tile it: pack, enqueue, wait, account;
   * the ``pack`` span's byte counters are the ``nbytes`` of what the run
-    hands the program: the packed grid, or for SFLv3 the hospitals'
-    arrays and the index grid the program gathers from;
+    hands the program: the packed grid, or for an unsharded split-family
+    run the hospitals' arrays and the index grid the program gathers
+    from;
   * the ``account`` span's wire counters are the transport's metering of
     the run, and what ``jax.checkpoint`` recomputes is scope ``remat``;
   * a profiler trace holds the spans as prefixed annotations with stats;
@@ -136,12 +137,18 @@ def test_run_spans_tile_the_run(tiny, method, k):
     assert gathers and all(e["args"]["parent"] == "pack" for e in gathers)
 
 
-@pytest.mark.parametrize("method", ["sflv3_ac", "sflv2_ac"])
-def test_pack_counters_are_the_packed_bytes(tiny, method, monkeypatch):
-    """An unsharded SFLv3 run hands the program the hospitals' arrays and
-    an index grid (``pack_run_index``) and gathers every batch slot on the
-    device; a strategy that still packs (SFLv2) counts ``pack_run``'s grid
-    and gathers none."""
+PACKS = [("sflv3_ac", None), ("sflv2_ac", None), ("sl_am", None),
+         ("fl", None), ("sl_am", 2)]
+
+
+@pytest.mark.parametrize("method,k", PACKS,
+                         ids=[f"{m}-k{k}" if k else m for m, k in PACKS])
+def test_pack_counters_are_the_packed_bytes(tiny, method, k, monkeypatch):
+    """An unsharded split-family run (SFLv3, SFLv2, SL) hands the program
+    the hospitals' arrays and an index grid (``pack_run_index``) and
+    gathers every batch slot on the device; a run that still packs (FL's
+    ``pack_run``, a participating run's slot grid) counts the grid the
+    host packed and gathers none."""
     from repro.core.strategies import engine
     pack_run = engine.pack_run
     seen = {}
@@ -149,26 +156,27 @@ def test_pack_counters_are_the_packed_bytes(tiny, method, monkeypatch):
     def spy(name):
         fn = getattr(engine, name)
 
-        def wrapped(*a, **k):
-            out = fn(*a, **k)
+        def wrapped(*a, **kw):
+            out = fn(*a, **kw)
             seen.setdefault(name, out)
             return out
         return wrapped
 
-    for name in ("pack_run", "pack_run_index"):
+    for name in ("pack_run", "pack_run_index", "pack_participation_run"):
         monkeypatch.setattr(engine, name, spy(name))
     tr = Tracer()
-    _train(tiny, method, tracer=tr)
+    part = None if k is None else Participation(n_global=N, k=k)
+    _train(tiny, method, tracer=tr, participation=part)
     pack = tr.find("pack")["args"]
     enqueue = tr.find("enqueue")["args"]
     children = [e["name"] for e in tr.events
                 if e["args"].get("parent") == "pack"]
-    if method == "sflv3_ac":
+    if method != "fl" and k is None:
         assert set(seen) == {"pack_run_index"}
         data, idx, packed = seen["pack_run_index"]
         assert set(data) == {"image", "label", "mask"}
-        assert {k: pack[f"bytes_{k}"] for k in data} == {
-            k: sum(a.nbytes for a in v) for k, v in data.items()}
+        assert {key: pack[f"bytes_{key}"] for key in data} == {
+            key: sum(a.nbytes for a in v) for key, v in data.items()}
         assert pack["bytes_index"] == idx.nbytes
         assert children == ["gather"]                    # nothing stacked
         e, c, nb = idx.shape[:3]
@@ -176,28 +184,34 @@ def test_pack_counters_are_the_packed_bytes(tiny, method, monkeypatch):
         # the grid the host packed for the same run before
         old_grid = sum(a.nbytes for a in pack_run(
             tiny[0], BATCH, np.random.default_rng(0), EPOCHS)[0].values())
-        assert enqueue["program"] == "sflv3_run"
-        assert enqueue["bytes_host"] >= (sum(pack[f"bytes_{k}"] for k in data)
+        assert enqueue["program"] == ("sflv3_run" if method == "sflv3_ac"
+                                      else "interleaved_run")
+        assert enqueue["bytes_host"] >= (sum(pack[f"bytes_{key}"] for key in data)
                                          + idx.nbytes)
         assert enqueue["bytes_host"] < old_grid
-        # every step trains every hospital's full batch: the run's images
-        assert tr.find("run")["args"]["images"] == EPOCHS * nb * N * BATCH
+        # SFLv3 trains every hospital's full batch at every step
+        per_epoch = (nb * N if method == "sflv3_ac"
+                     else sum(packed.n_batches))
+        assert tr.find("run")["args"]["images"] == EPOCHS * per_epoch * BATCH
+        assert pack["real_batches"] == e * sum(packed.n_batches) < e * c * nb
     else:
-        assert set(seen) == {"pack_run"}
-        batches, packed = seen["pack_run"]
-        assert {k: pack[f"bytes_{k}"] for k in batches} == {
-            k: v.nbytes for k, v in batches.items()}
+        name = "pack_run" if k is None else "pack_participation_run"
+        assert set(seen) == {name}
+        batches, packed = seen[name]
+        assert {key: pack[f"bytes_{key}"] for key in batches} == {
+            key: v.nbytes for key, v in batches.items()}
         assert set(batches) == {"image", "label", "mask"}
         e, c, nb = batches["label"].shape[:3]
         assert pack["batch_slots"] == e * c * nb
-        assert pack["device_gather"] == 0
-        assert children == ["gather"] * EPOCHS + ["stack"]
-        assert enqueue["program"] == "interleaved_run"
+        assert pack["device_gather"] == 0 and "bytes_index" not in pack
+        assert children == (["gather"] * EPOCHS + ["stack"] if k is None
+                            else ["gather"])
+        assert enqueue["program"] == ("fl_run" if k is None
+                                      else "interleaved_run_participation")
         assert enqueue["bytes_host"] >= sum(v.nbytes
                                             for v in batches.values())
-        assert tr.find("run")["args"]["images"] == (
-            EPOCHS * sum(packed.n_batches) * BATCH)
-    assert pack["real_batches"] == e * sum(packed.n_batches) < e * c * nb
+        assert pack["real_batches"] == packed.mask.sum() * (
+            e if k is None else 1) < e * c * nb
     assert enqueue["bytes_in"] > enqueue["bytes_host"]
 
 
