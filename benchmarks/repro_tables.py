@@ -17,6 +17,7 @@ import os
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro import optim as O
@@ -79,8 +80,8 @@ def run_method(label, method, nls, arch, clients, epochs, batch_size, lr,
         epoch_times.append(time.time() - t0)
         vl = strat.val_loss(state, clients, batch_size)
         if vl < best["val_loss"]:
-            best = {"val_loss": vl,
-                    "state": jax.tree.map(lambda x: x, state)}
+            # a copy: the next epoch's program donates the state's buffers
+            best = {"val_loss": vl, "state": jax.tree.map(jnp.copy, state)}
         print(f"    ep{ep} loss={log.mean_loss:.3f} val={vl:.3f} "
               f"t={epoch_times[-1]:.1f}s", flush=True)
 

@@ -20,10 +20,12 @@ def make_strategy(method: str, adapter, opt_factory, n_clients,
     secure aggregation for FL.
 
     ``engine`` selects the execution path: ``"compiled"`` (the default;
-    repro.core.strategies.engine: whole epochs — and whole multi-epoch
-    ``Strategy.run``s — as single XLA programs, scan-over-batches /
-    scan-over-rounds / vmap-over-hospitals) or ``"stepwise"`` (legacy,
-    one jitted dispatch per mini-batch — kept as the parity oracle).
+    repro.core.strategies.engine: a whole multi-epoch ``Strategy.run`` as
+    ONE XLA program, scan-over-rounds / scan-over-batches /
+    vmap-over-hospitals; ``run_epoch`` is a one-round run, and only FL's
+    host-side aggregations keep a compiled program per round) or
+    ``"stepwise"`` (legacy, one jitted dispatch per mini-batch — kept as
+    the parity oracle).
     Both are numerically equivalent to 1e-5 (tests/test_engine.py);
     transport byte accounting and simulated wire timelines are identical
     under either engine (``wire.simulator.timeline_from_accounting``).

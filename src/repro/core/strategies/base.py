@@ -14,10 +14,10 @@ through hospital i's own client segment(s); FL/centralized have one model.
 Two execution engines share the SAME pure step functions (``full_step_fn``
 / ``split_step_fn`` / ``sflv3_step_fn``):
 
-  * ``compiled`` (the DEFAULT; repro.core.strategies.engine): whole epochs
-    — and whole multi-epoch runs via ``Strategy.run`` — lowered to single
-    XLA programs: ``lax.scan`` over batches (and rounds), ``vmap`` over
-    the hospital axis where semantics allow.
+  * ``compiled`` (the DEFAULT; repro.core.strategies.engine): a whole
+    multi-epoch ``Strategy.run`` lowered to ONE XLA program — ``lax.scan``
+    over rounds and batches, ``vmap`` over the hospital axis where
+    semantics allow; ``run_epoch`` is a one-round run.
   * ``stepwise`` (legacy; kept as the parity oracle): a Python host loop
     dispatching one jitted step per mini-batch.
 
@@ -179,7 +179,9 @@ class Strategy:
     def setup(self, key):
         raise NotImplementedError
 
-    def run_epoch(self, state, client_data, rng, batch_size):
+    def _run_epoch_stepwise(self, state, client_data, rng, batch_size):
+        """One epoch as a host loop of per-batch jitted steps: the parity
+        oracle the compiled programs are tested against."""
         raise NotImplementedError
 
     def params_for_eval(self, state, client_idx) -> dict:
@@ -206,14 +208,58 @@ class Strategy:
         return ServableModel(adapter=self.adapter, params=params,
                              shared=self.shared_eval_params, meta=m)
 
-    # -- whole-run training ----------------------------------------------------
+    # -- training ---------------------------------------------------------------
+    def run_epoch(self, state, client_data, rng, batch_size):
+        """Train one epoch (one FL round); returns ``(state, EpochLog)``.
+
+        Under the compiled engine this is ``run(n_epochs=1)`` without the
+        ``run`` span: the same one-round whole-run program, the same
+        shuffles and PRNG counter, so the state and log match it bit for
+        bit.  A participating strategy refuses it: each call would be
+        round 0 again, the same cohort drawn every time while its privacy
+        is accounted at the sampled rate; ``run`` numbers its rounds.
+        """
+        if self.participation is not None:
+            raise ValueError(
+                "run_epoch with participation= would redraw round 0's "
+                "cohort at every call; use run(n_epochs=...), which "
+                "samples a cohort per round")
+        if self.engine == "stepwise":
+            return self._run_epoch_stepwise(state, client_data, rng,
+                                            batch_size)
+        if not self._whole_run:
+            return self._run_epoch_compiled(state, client_data, rng,
+                                            batch_size)
+        state, (log,) = self._run(state, client_data, rng, batch_size, 1)
+        return state, log
+
     @property
     def _whole_run(self) -> bool:
-        """Strategy supports lowering a multi-epoch run into ONE program."""
-        return False
+        """The compiled engine lowers a multi-epoch run into ONE program;
+        only FedAvg's host-side aggregations say otherwise."""
+        return True
 
     def _run_compiled(self, state, client_data, rng, batch_size, n_epochs):
+        """The whole run as one program, for a run that has a batch."""
         raise NotImplementedError
+
+    def _run_epoch_compiled(self, state, client_data, rng, batch_size):
+        """One compiled round for a strategy without a whole run."""
+        raise NotImplementedError
+
+    def _empty_run(self, client_data, batch_size) -> bool:
+        """No hospital's training set yields a single batch."""
+        from repro.core.strategies import engine as ENG
+        return ENG.empty_run([len(next(iter(d.values())))
+                              for d in client_data], batch_size,
+                             self.drop_remainder)
+
+    def _empty_epoch(self, state, client_data, rng, batch_size):
+        """One epoch of a run without a batch: the shuffles a pack of it
+        would draw from ``rng``, and an empty log."""
+        from repro.core.strategies import engine as ENG
+        ENG.pack_epoch(client_data, batch_size, rng, self.drop_remainder)
+        return EpochLog([], 0, client_steps=[0] * self.n_clients)
 
     def run(self, state, client_data, rng, batch_size, n_epochs,
             observe=None):
@@ -225,8 +271,8 @@ class Strategy:
         the FedAvg aggregation / SFLv2 client averaging folded in — so one
         host dispatch executes every epoch.  Strategies that cannot fold
         their round boundary in-graph (secure aggregation's host-side
-        masked uploads) and the stepwise engine fall back to a per-epoch
-        loop; both orders consume ``rng`` and the PRNG step counter
+        masked uploads) and the stepwise engine loop over ``run_epoch``;
+        both orders consume ``rng`` and the PRNG step counter
         identically, so results match the fused path to float round-off.
 
         ``observe`` (repro.obs.Telemetry | True | False | None) overrides
@@ -259,22 +305,24 @@ class Strategy:
             self._tel_active = prev
 
     def _run(self, state, client_data, rng, batch_size, n_epochs):
-        if self.engine == "compiled" and self._whole_run:
-            out = self._run_compiled(state, client_data, rng, batch_size,
-                                     n_epochs)
-            if out is not None:  # None: degenerate run, fall back
-                return out
+        if self.engine == "stepwise" or not self._whole_run:
+            logs = []
+            for i in range(n_epochs):
+                with self._span(f"round {i}"):
+                    state, log = self.run_epoch(state, client_data, rng,
+                                                batch_size)
+                logs.append(log)
+            return state, logs
+        if self._empty_run(client_data, batch_size):
+            # nothing to compile: a degenerate participating run trains
+            # nothing, any other logs one empty epoch per epoch
             if self.participation is not None:
-                # the per-epoch fallback has no slot packing; a
-                # degenerate participating run trains nothing
                 return state, []
-        logs = []
-        for i in range(n_epochs):
-            with self._span(f"round {i}"):
-                state, log = self.run_epoch(state, client_data, rng,
-                                            batch_size)
-            logs.append(log)
-        return state, logs
+            return state, [self._empty_epoch(state, client_data, rng,
+                                             batch_size)
+                           for _ in range(n_epochs)]
+        return self._run_compiled(state, client_data, rng, batch_size,
+                                  n_epochs)
 
     def _finish_run(self, client_data, batch_size, logs):
         """Assemble ``last_run_telemetry`` (one RoundTelemetry per epoch
@@ -338,21 +386,6 @@ class Strategy:
         if self._tracer is None:
             return contextlib.nullcontext()
         return self._tracer.span(name, **args)
-
-    @staticmethod
-    def _pack_span(sp, batches: dict, slots: int, real: int,
-                   device_gather: int = 0):
-        """The ``pack`` span's counters, when traced: bytes handed to the
-        program per data key (a packed grid, or the hospitals' arrays and
-        the ``index`` grid), batch slots packed, the real (non-padding)
-        batches among them, and the batch slots the program gathers on
-        the device (0 where the host packed them)."""
-        if sp is not None:
-            sp.set(**{f"bytes_{k}": sum(int(a.nbytes)
-                                        for a in jax.tree.leaves(v))
-                      for k, v in batches.items()},
-                   batch_slots=int(slots), real_batches=int(real),
-                   device_gather=int(device_gather))
 
     def _enqueue(self, fn, args, stash: bool = True):
         """Call a compiled program under the ``enqueue`` span: the jitted

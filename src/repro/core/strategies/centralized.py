@@ -8,6 +8,12 @@ from repro.core.strategies.base import (Strategy, EpochLog, make_full_step,
                                         np_batches)
 
 
+def _pool(client_data) -> dict:
+    """Every hospital's training rows as one set."""
+    return {k: np.concatenate([d[k] for d in client_data])
+            for k in client_data[0]}
+
+
 class Centralized(Strategy):
     name = "centralized"
     shared_eval_params = True
@@ -36,11 +42,8 @@ class Centralized(Strategy):
         return T.rounds_client_major(tel, arr, mets,
                                      np.ones((1, nb), bool), 1)[0]
 
-    def run_epoch(self, state, client_data, rng, batch_size):
-        pooled = {k: np.concatenate([d[k] for d in client_data])
-                  for k in client_data[0]}
-        if self.engine == "compiled":
-            return self._run_epoch_compiled(state, pooled, rng, batch_size)
+    def _run_epoch_stepwise(self, state, client_data, rng, batch_size):
+        pooled = _pool(client_data)
         tel = self._tel
         step = self._step if tel is None else self._get_obs(
             "_step_obs", tel,
@@ -72,66 +75,31 @@ class Centralized(Strategy):
                  for k in (met_vals[0] if met_vals else {})})
         return state, log
 
-    def _run_epoch_compiled(self, state, pooled, rng, batch_size):
+    def _empty_run(self, client_data, batch_size):
+        # the pooled set is cut into batches, not the hospitals' own
         from repro.core.strategies import engine as ENG
-        tel = self._tel
-        with self._span("pack") as sp:
-            packed = ENG.pack_epoch([pooled], batch_size, rng,
-                                    self.drop_remainder, span=self._span)
-            self._pack_span(sp, packed.batches, packed.mask.size,
-                            sum(packed.n_batches))
-        nb = packed.n_batches[0]
-        if nb == 0:
-            return state, EpochLog([], 0)
-        if tel is None:
-            if not hasattr(self, "_epoch_c"):
-                self._epoch_c = ENG.make_seq_epoch(self.adapter, self._opt,
-                                                   self.privacy)
-            epoch_fn = self._epoch_c
-        else:
-            epoch_fn = self._get_obs(
-                "_epoch_obs_c", tel,
-                lambda: ENG.make_seq_epoch(self.adapter, self._opt,
-                                           self.privacy, tel))
-        key_idx = np.zeros((packed.nb_max,), np.uint32)
-        if self._keyed:
-            key_idx[:nb] = self._take_key_indices(nb)
-        batches = {k: v[0] for k, v in packed.batches.items()}
-        ex_w = None if packed.ex_weights is None else packed.ex_weights[0]
-        out = self._enqueue(epoch_fn, (
-            state["params"], state["opt"], batches, packed.mask[0], ex_w,
-            key_idx, self._privacy_base_key()), stash=False)
-        state["params"], state["opt"] = out[0], out[1]
-        losses = self._wait(out[2])
-        with self._span("account"):
-            flat = [float(x) for x in losses[:nb]]
-            for ci in range(self.n_clients):
-                self._dp_account(ci, packed.n_samples[0], batch_size,
-                                 count=nb)
-            log = EpochLog(flat, nb, weights=packed.step_examples[0])
-            if tel is not None:
-                log.telemetry = self._round_telemetry(
-                    tel, flat,
-                    {k: np.asarray(v)[:nb] for k, v in out[3].items()})
-        return state, log
+        return ENG.empty_run([sum(len(next(iter(d.values())))
+                                  for d in client_data)], batch_size,
+                             self.drop_remainder)
 
-    @property
-    def _whole_run(self):
-        return True
+    def _empty_epoch(self, state, client_data, rng, batch_size):
+        from repro.core.strategies import engine as ENG
+        ENG.pack_epoch([_pool(client_data)], batch_size, rng,
+                       self.drop_remainder)
+        return EpochLog([], 0)
 
     def _run_compiled(self, state, client_data, rng, batch_size, n_epochs):
         from repro.core.strategies import engine as ENG
         tel = self._tel
         with self._span("pack") as sp:
-            pooled = {k: np.concatenate([d[k] for d in client_data])
-                      for k in client_data[0]}
-            if ENG.empty_run([pooled], batch_size, self.drop_remainder):
-                return None
+            pooled = _pool(client_data)
             batches, packed = ENG.pack_run([pooled], batch_size, rng,
                                            n_epochs, self.drop_remainder,
                                            span=self._span)
-            self._pack_span(sp, batches, n_epochs * packed.mask.size,
-                            n_epochs * sum(packed.n_batches))
+            if sp is not None:
+                sp.set(**ENG.pack_counters(batches,
+                                           n_epochs * packed.mask.size,
+                                           n_epochs * sum(packed.n_batches)))
         nb = packed.n_batches[0]
         if tel is None:
             if not hasattr(self, "_run_c"):

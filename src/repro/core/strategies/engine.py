@@ -3,9 +3,9 @@
 The stepwise engine (the legacy path in each strategy, kept as the parity
 reference) dispatches one jitted step per mini-batch per hospital from a
 Python host loop — wall-clock is dominated by dispatch overhead and
-hospitals run strictly sequentially.  This module lowers a WHOLE epoch —
-and, via the ``make_*_run`` builders, a whole multi-epoch training RUN —
-into a single XLA program instead:
+hospitals run strictly sequentially.  This module lowers a whole
+multi-epoch training RUN (the ``make_*_run`` builders) into a single XLA
+program instead:
 
   * **pad-and-mask layout** — each hospital's shuffled epoch is packed into
     rectangular ``[n_clients, n_batches, batch, ...]`` arrays plus a
@@ -16,8 +16,9 @@ into a single XLA program instead:
     the **index layout** instead (``pack_run_index``): the hospitals' own
     arrays and an int32 ``[n_epochs, n_clients, n_batches, batch]`` grid
     of row ids, from which the program gathers each step's batch on the
-    device; the host copies no image bytes.  Every other run takes the
-    packed grid.
+    device; the host copies no image bytes.  ``pack_run_inputs`` is the
+    one place that chooses between the two for the split family; every
+    other run takes the packed grid.
   * **scan over batches, vmap over hospitals** where semantics allow it:
     FL local epochs are independent per hospital, so the per-client
     ``lax.scan`` is wrapped in a ``vmap`` over the stacked hospital axis.
@@ -208,8 +209,9 @@ def _step_key(base_key, idx, keyed):
 def _fl_epoch_body(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
                    placement=None, telemetry=None):
     """Traceable FL round: vmap-over-hospitals of scan-over-batches.
-    Shared verbatim by ``make_fl_epoch`` and ``make_fl_run``'s round scan
-    — one definition is what keeps the two numerically identical.
+    Shared verbatim by ``make_fl_epoch`` (host-aggregated rounds) and
+    ``make_fl_run``'s round scan — one definition is what keeps the two
+    numerically identical.
 
     With an enabled ``placement`` the hospital axis runs under
     ``shard_map`` on the "hosp" mesh: each device vmaps over its own
@@ -261,7 +263,10 @@ def _fl_epoch_body(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
 
 def make_fl_epoch(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
                   placement=None, telemetry=None):
-    """FL round as vmap-over-hospitals of scan-over-batches.
+    """FL round as vmap-over-hospitals of scan-over-batches, for the
+    rounds whose aggregation runs on the host: secure aggregation, or an
+    aggregator that is not ``scan_compatible`` (``FedAvg._whole_run``).
+    These are its only users; every other run takes ``make_fl_run``.
 
     Every hospital starts from the broadcast global params with a fresh
     optimizer (FedAvg semantics); masked steps are no-ops via
@@ -277,7 +282,7 @@ def make_fl_epoch(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
 def _seq_epoch_body(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
                     telemetry=None):
     """Traceable centralized epoch: one scan-over-batches with persistent
-    optimizer state; shared by ``make_seq_epoch`` and ``make_seq_run``."""
+    optimizer state; ``make_seq_run`` scans it over epochs."""
     step, keyed = full_step_fn(adapter, opt, privacy, telemetry)
     observed = telemetry is not None
 
@@ -299,21 +304,16 @@ def _seq_epoch_body(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
     return epoch
 
 
-def make_seq_epoch(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
-                   telemetry=None):
-    """Centralized epoch as a single scan-over-batches (one 'hospital',
-    persistent optimizer state).  Returns ``epoch(params, opt_state,
-    batches, mask, ex_w, key_idx, base_key) -> (params, opt_state,
-    [NB] losses)`` — plus a trailing ``met`` dict of ``[NB]`` taps with a
-    ``telemetry`` spec."""
-    return jax.jit(_seq_epoch_body(adapter, opt, privacy, telemetry))
-
-
 def _interleaved_epoch_body(adapter: SplitAdapter, opt_client: O.Optimizer,
                             opt_server: O.Optimizer, transport=None,
                             privacy=None, telemetry=None):
     """Traceable SL/SFLv2 epoch: ONE scan over the dense schedule array;
-    shared by ``make_interleaved_epoch`` and ``make_interleaved_run``."""
+    ``make_interleaved_run`` scans it over epochs.
+
+    The shared server segment forces sequential semantics: each scan step
+    gathers client ``c``'s segment + optimizer slice from the stacked
+    hospital axis, runs the exact split step, and scatters the update
+    back."""
     step, keyed = split_step_fn(adapter, opt_client, opt_server, transport,
                                 privacy, telemetry)
     observed = telemetry is not None
@@ -368,30 +368,13 @@ def _hospital_rows(data, rows, c):
     return jax.lax.switch(c, [branch(i) for i in range(len(sizes))], rows)
 
 
-def make_interleaved_epoch(adapter: SplitAdapter, opt_client: O.Optimizer,
-                           opt_server: O.Optimizer, transport=None,
-                           privacy=None, telemetry=None):
-    """SL/SFLv2 epoch as ONE scan over the dense schedule array.
-
-    The shared server segment forces sequential semantics: each scan step
-    gathers client ``c``'s segment + optimizer slice from the stacked
-    hospital axis, runs the exact split step, and scatters the update back.
-    Returns ``epoch(stacked_clients, server, stacked_c_opts, s_opt,
-    batches, ex_w, sched, key_idx, base_key) -> (stacked_clients, server,
-    stacked_c_opts, s_opt, [steps] losses)`` — plus a trailing ``met``
-    dict of ``[steps]`` metric taps with a ``telemetry`` spec.
-    """
-    return jax.jit(_interleaved_epoch_body(adapter, opt_client, opt_server,
-                                           transport, privacy, telemetry))
-
-
 def _sflv3_epoch_body(adapter: SplitAdapter, opt_client: O.Optimizer,
                       opt_server: O.Optimizer, n_clients: int,
                       transport=None, privacy=None, client_weights=None,
                       placement=None, telemetry=None):
     """Traceable SplitFedv3/v1 epoch: scan over synchronous steps with the
-    vmapped per-client step inside; shared by ``make_sflv3_epoch`` and
-    ``make_sflv3_run``.  ``n_clients`` is the ARRAY hospital count (a
+    vmapped per-client step inside; ``make_sflv3_run`` scans it over
+    epochs.  ``n_clients`` is the ARRAY hospital count (a
     placement's padded ``c_pad``); ``client_weights`` zeroes phantom rows
     out of the server-gradient average.
 
@@ -463,28 +446,12 @@ def _sflv3_epoch_body(adapter: SplitAdapter, opt_client: O.Optimizer,
     return epoch
 
 
-def make_sflv3_epoch(adapter: SplitAdapter, opt_client: O.Optimizer,
-                     opt_server: O.Optimizer, n_clients: int, transport=None,
-                     privacy=None, client_weights=None, placement=None,
-                     telemetry=None):
-    """SplitFedv3 epoch: scan over synchronous steps, vmap over hospitals
-    inside each step (the step fn already vmaps), with the wrap-around
-    batch index precomputed as a dense ``[steps, n_clients]`` array.
-    Returns ``epoch(stacked_clients, server, c_opt, s_opt, batches, b_idx,
-    key_idx, base_key) -> (..., [steps, C] losses)`` — plus a trailing
-    ``met`` dict of ``[steps, C]`` metric taps with a ``telemetry``
-    spec."""
-    return jax.jit(_sflv3_epoch_body(adapter, opt_client, opt_server,
-                                     n_clients, transport, privacy,
-                                     client_weights, placement, telemetry))
-
-
 def _update_cosine(stacked, gp, new_gp, eps=1e-12):
     """Per-hospital cosine between each local FedAvg update delta
     (``local_c - global``) and the aggregated mean delta
     (``new_global - global``) — the round's update-agreement tap
     (traceable; shared by ``make_fl_run``'s round body and the jitted
-    host-callable ``update_cosine`` the per-epoch paths use).  Zero
+    host-callable ``update_cosine``).  Zero
     deltas (phantom rows, no-op rounds) report cosine 0."""
     C = jax.tree.leaves(stacked)[0].shape[0]
     deltas = jnp.concatenate(
@@ -502,8 +469,9 @@ def _update_cosine(stacked, gp, new_gp, eps=1e-12):
 
 @jax.jit
 def update_cosine(stacked, gp, new_gp):
-    """Host-callable ``_update_cosine`` for the per-epoch / stepwise FL
-    paths (the whole-run engine computes it inside the round scan)."""
+    """Host-callable ``_update_cosine`` for the host-aggregated and
+    stepwise FL rounds (the whole-run engine computes it inside the round
+    scan)."""
     return _update_cosine(stacked, gp, new_gp)
 
 
@@ -569,17 +537,14 @@ def abstract_args(args):
 
     return jax.tree.map(abstract, args)
 
-def empty_run(client_data, batch_size: int,
-              drop_remainder: bool = True) -> bool:
-    """True when no hospital yields a single batch.  Checked BEFORE
-    ``pack_run`` so a degenerate ``Strategy.run`` can fall back to the
-    per-epoch path without having consumed any shuffle draws from the
-    host rng."""
-    for d in client_data:
-        n = len(next(iter(d.values())))
-        if _client_batch_count(n, batch_size, drop_remainder)[0]:
-            return False
-    return True
+
+def empty_run(sizes, batch_size: int, drop_remainder: bool = True) -> bool:
+    """True when no training set of ``sizes`` rows yields a single batch.
+    ``Strategy._run`` checks it before any pack, so a run with nothing to
+    train compiles nothing and draws only the shuffles its epochs would
+    (``Strategy._empty_epoch``)."""
+    return not any(_client_batch_count(n, batch_size, drop_remainder)[0]
+                   for n in sizes)
 
 
 def pack_run(client_data, batch_size: int, rng, n_epochs: int,
@@ -643,6 +608,54 @@ def pack_run_index(client_data, batch_size: int, rng, n_epochs: int,
     return data, idx.reshape(n_epochs, C, NB, batch_size), meta
 
 
+def pack_counters(batches: dict, slots: int, real: int,
+                  device_gather: int = 0) -> dict:
+    """The ``pack`` span's counters: bytes handed to the program per data
+    key (a packed grid, or the hospitals' arrays and the ``index`` grid),
+    batch slots packed, the real (non-padding) batches among them, and
+    the batch slots the program gathers on the device (0 where the host
+    packed them)."""
+    return {**{f"bytes_{k}": sum(int(a.nbytes) for a in jax.tree.leaves(v))
+               for k, v in batches.items()},
+            "batch_slots": int(slots), "real_batches": int(real),
+            "device_gather": int(device_gather)}
+
+
+def pack_run_inputs(client_data, batch_size: int, rng, n_epochs: int,
+                    drop_remainder: bool, placement, span=_no_span):
+    """A split-family whole run's batches, in the layout ``placement``
+    takes: the one place that chooses between the two.
+
+    Sharded (``placement.enabled``), each device holds its own hospitals'
+    rows, so the host packs ``pack_run``'s grid and the program takes
+    ``(grid,)``.  Otherwise the program gathers every batch on the device
+    from the hospitals' own arrays and ``pack_run_index``'s row ids:
+    ``(data, idx)``.  Both consume ``rng`` alike.
+
+    Returns ``(inputs, example, meta, counters)``: the program's batch
+    inputs; a zero batch of the run's batch shape for the wire accounting,
+    which reads shapes only; the ``PackedEpoch`` meta; and the ``pack``
+    span's counters (``pack_counters``).
+    """
+    if placement.enabled:
+        grid, meta = pack_run(client_data, batch_size, rng, n_epochs,
+                              drop_remainder, pad_clients=placement.n_pad,
+                              span=span)
+        inputs, handed = (grid,), grid
+    else:
+        data, idx, meta = pack_run_index(client_data, batch_size, rng,
+                                         n_epochs, drop_remainder,
+                                         pad_clients=placement.n_pad,
+                                         span=span)
+        inputs, handed = (data, idx), {**data, "index": idx}
+    slots = n_epochs * meta.mask.size
+    counters = pack_counters(handed, slots, n_epochs * sum(meta.n_batches),
+                             0 if placement.enabled else slots)
+    example = {k: np.zeros((batch_size, *v.shape[1:]), v.dtype)
+               for k, v in client_data[0].items()}
+    return inputs, example, meta, counters
+
+
 def make_fl_run(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
                 placement=None, telemetry=None, aggregator=None):
     """Whole FL training run as ONE program: ``lax.scan`` over rounds, each
@@ -703,8 +716,9 @@ def make_fl_run(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
 
 def make_seq_run(adapter: SplitAdapter, opt: O.Optimizer, privacy=None,
                  telemetry=None):
-    """Whole centralized run: scan over epochs around ``make_seq_epoch``'s
-    scan-over-batches body (persistent optimizer state across epochs).
+    """Whole centralized run: scan over epochs around the
+    scan-over-batches epoch body (persistent optimizer state across
+    epochs).
     Returns ``run(params, opt_state, batches[E,NB,...], mask[NB], ex_w,
     key_idx[E,NB], base_key) -> (params, opt_state, [E,NB] losses)`` —
     plus a trailing ``met`` dict of ``[E, NB]`` taps with a ``telemetry``
@@ -734,7 +748,7 @@ def make_interleaved_run(adapter: SplitAdapter, opt_client: O.Optimizer,
                          client_weights=None, placement=None,
                          telemetry=None):
     """Whole SL/SFLv2 run: scan over epochs around the scanned schedule
-    interleave body ``make_interleaved_epoch`` jits.  ``sync_clients``
+    interleave body (``_interleaved_epoch_body``).  ``sync_clients``
     folds the SFLv2 end-of-epoch client fed-averaging into the round
     body (``client_weights`` excludes placement phantom rows from it).
     The schedule array is epoch-invariant (batch counts never
@@ -796,10 +810,11 @@ def make_sflv3_run(adapter: SplitAdapter, opt_client: O.Optimizer,
                    privacy=None, sync_clients: bool = False,
                    client_weights=None, placement=None, telemetry=None):
     """Whole SplitFedv3/v1 run: scan over epochs around the synchronous-
-    step scan body ``make_sflv3_epoch`` jits (wrap-around index grid
-    ``b_idx`` is epoch-invariant); ``sync_clients`` folds SFLv1's client
-    fed-averaging into the round body; ``client_weights`` excludes
-    placement phantom rows from server-gradient averaging and syncs.
+    step scan body (``_sflv3_epoch_body``; its ``[steps, C]`` wrap-around
+    batch index ``b_idx`` is epoch-invariant); ``sync_clients`` folds
+    SFLv1's client fed-averaging into the round body; ``client_weights``
+    excludes placement phantom rows from server-gradient averaging and
+    syncs.
 
     Unsharded, the run gathers its batches on the device from
     ``pack_run_index``'s output: ``run(stacked_clients, server, c_opt,
@@ -1190,7 +1205,7 @@ def make_sflv3_run_participation(adapter: SplitAdapter,
 
 
 # ---------------------------------------------------------------------------
-# host-side helpers shared by the strategies' compiled run_epoch paths
+# host-side helpers shared by the strategies' compiled paths
 # ---------------------------------------------------------------------------
 
 def client_major_log(losses, packed: PackedEpoch):

@@ -49,6 +49,11 @@ class FedAvg(Strategy):
             self._agg = None                 # built in setup (needs SecAgg)
         else:
             self._agg = AGG.make_aggregator(self.aggregator_spec)
+            if self.participation is not None and \
+                    not self._agg.scan_compatible:
+                raise ValueError("participation= needs an aggregator "
+                                 "that folds into the round scan "
+                                 "(scan_compatible)")
 
     def setup(self, key):
         params = self.adapter.init(key)
@@ -80,10 +85,7 @@ class FedAvg(Strategy):
         return T.rounds_client_major(tel, np.asarray(losses)[None], metrics,
                                      mask, self.n_clients, extra)[0]
 
-    def run_epoch(self, state, client_data, rng, batch_size):
-        if self.engine == "compiled":
-            return self._run_epoch_compiled(state, client_data, rng,
-                                            batch_size)
+    def _run_epoch_stepwise(self, state, client_data, rng, batch_size):
         tel = self._tel
         step = self._step if tel is None else self._get_obs(
             "_step_obs", tel,
@@ -130,6 +132,8 @@ class FedAvg(Strategy):
         return state, log
 
     def _run_epoch_compiled(self, state, client_data, rng, batch_size):
+        """One compiled round whose aggregation runs on the host (secure
+        aggregation, or an aggregator that is not ``scan_compatible``)."""
         from repro.core.strategies import engine as ENG
         tel = self._tel
         place = self.placement
@@ -138,8 +142,9 @@ class FedAvg(Strategy):
                                     self.drop_remainder,
                                     pad_clients=place.n_pad,
                                     span=self._span)
-            self._pack_span(sp, packed.batches, packed.mask.size,
-                            sum(packed.n_batches))
+            if sp is not None:
+                sp.set(**ENG.pack_counters(packed.batches, packed.mask.size,
+                                           sum(packed.n_batches)))
         if packed.nb_max == 0:
             return state, EpochLog([], 0,
                                    client_steps=[0] * self.n_clients)
@@ -192,8 +197,8 @@ class FedAvg(Strategy):
     @property
     def _whole_run(self):
         # secagg aggregates host-side per-round (masked uploads) and keeps
-        # the per-epoch dispatch path; so does any non-scan-compatible
-        # custom aggregator
+        # a compiled round per epoch (``_run_epoch_compiled``); so does
+        # any non-scan-compatible custom aggregator
         if self._secagg_on:
             return False
         return self._agg.scan_compatible
@@ -207,8 +212,6 @@ class FedAvg(Strategy):
 
     def _run_compiled(self, state, client_data, rng, batch_size, n_epochs):
         from repro.core.strategies import engine as ENG
-        if ENG.empty_run(client_data, batch_size, self.drop_remainder):
-            return None                        # empty run: per-epoch path
         if self.participation is not None:
             return self._run_participation(state, client_data, rng,
                                            batch_size, n_epochs)
@@ -219,8 +222,10 @@ class FedAvg(Strategy):
                                            n_epochs, self.drop_remainder,
                                            pad_clients=place.n_pad,
                                            span=self._span)
-            self._pack_span(sp, batches, n_epochs * packed.mask.size,
-                            n_epochs * sum(packed.n_batches))
+            if sp is not None:
+                sp.set(**ENG.pack_counters(batches,
+                                           n_epochs * packed.mask.size,
+                                           n_epochs * sum(packed.n_batches)))
         if tel is None:
             if not hasattr(self, "_run_c"):
                 self._run_c = ENG.make_fl_run(
@@ -288,7 +293,9 @@ class FedAvg(Strategy):
             batches, pack = ENG.pack_participation_run(
                 client_data, batch_size, rng, n_epochs, part,
                 self.drop_remainder, span=self._span)
-            self._pack_span(sp, batches, pack.mask.size, pack.mask.sum())
+            if sp is not None:
+                sp.set(**ENG.pack_counters(batches, pack.mask.size,
+                                           pack.mask.sum()))
         nbs = pack.n_batches
         T_N = int(sum(nbs))
         prefix = np.concatenate([[0], np.cumsum(nbs)[:-1]]).astype(np.int64)

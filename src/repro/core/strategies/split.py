@@ -47,7 +47,7 @@ class SplitLearning(Strategy):
 
     @contextlib.contextmanager
     def _account_span(self):
-        """The ``account`` span of a compiled run or epoch.  With a
+        """The ``account`` span of a compiled run.  With a
         transport it gets the run's wire counters from the transport's
         accounting inside it: ``wire_bytes`` and ``wire_bytes_raw`` (both
         legs, on the wire and as float32) and ``cut_arrays`` (the arrays
@@ -100,10 +100,7 @@ class SplitLearning(Strategy):
              for k, v in metrics.items()},
             np.asarray(sched), self.n_clients)[0]
 
-    def run_epoch(self, state, client_data, rng, batch_size):
-        if self.engine == "compiled":
-            return self._run_epoch_compiled(state, client_data, rng,
-                                            batch_size)
+    def _run_epoch_stepwise(self, state, client_data, rng, batch_size):
         tel = self._tel
         step = self._step if tel is None else self._get_obs(
             "_step_obs", tel,
@@ -162,104 +159,19 @@ class SplitLearning(Strategy):
         state["stacked_clients"] = place.put(state["stacked_clients"])
         state["stacked_c_opts"] = place.put(state["stacked_c_opts"])
 
-    def _run_epoch_compiled(self, state, client_data, rng, batch_size):
-        from repro.core.strategies import engine as ENG
-        tel = self._tel
-        place = self.placement
-        with self._span("pack") as sp:
-            packed = ENG.pack_epoch(client_data, batch_size, rng,
-                                    self.drop_remainder,
-                                    pad_clients=place.n_pad,
-                                    span=self._span)
-            self._pack_span(sp, packed.batches, packed.mask.size,
-                            sum(packed.n_batches))
-        sched = schedule_array(self.schedule, packed.n_batches)
-        if len(sched) == 0:
-            self._end_of_epoch(state)        # SFLv2 still syncs clients
-            return state, EpochLog([], 0,
-                                   client_steps=[0] * self.n_clients)
-        if tel is None:
-            if not hasattr(self, "_epoch_c"):
-                self._epoch_c = ENG.make_interleaved_epoch(
-                    self.adapter, self._opt_c, self._opt_s, self.transport,
-                    self.privacy)
-            epoch_fn = self._epoch_c
-        else:
-            epoch_fn = self._get_obs(
-                "_epoch_obs_c", tel,
-                lambda: ENG.make_interleaved_epoch(
-                    self.adapter, self._opt_c, self._opt_s, self.transport,
-                    self.privacy, tel))
-        key_idx = (self._take_key_indices(len(sched)) if self._keyed
-                   else np.zeros((len(sched),), np.uint32))
-        self._ensure_stacked(state)
-        out = self._enqueue(epoch_fn, (
-            state["stacked_clients"], state["server"],
-            state["stacked_c_opts"], state["s_opt"],
-            place.put(packed.batches), place.put(packed.ex_weights),
-            sched, key_idx, self._privacy_base_key()), stash=False)
-        (state["stacked_clients"], state["server"],
-         state["stacked_c_opts"], state["s_opt"], losses) = out[:5]
-        losses = self._wait(losses)
-        with self._account_span():
-            flat, loss_w = ENG.scheduled_log(losses, sched, packed)
-            # the interleave program's output sharding is compiler-chosen:
-            # re-place so between-epoch state is always on the hosp mesh
-            state["stacked_clients"] = place.put(state["stacked_clients"])
-            state["stacked_c_opts"] = place.put(state["stacked_c_opts"])
-            self._account_compiled(
-                {k: v[0, 0] for k, v in packed.batches.items()}, packed,
-                batch_size)
-            self._end_of_epoch(state)
-            log = EpochLog(flat, len(flat), weights=loss_w,
-                           client_steps=list(
-                               packed.n_batches[:self.n_clients]))
-            if tel is not None:
-                log.telemetry = self._round_telemetry(
-                    tel, losses,
-                    {k: np.asarray(v) for k, v in out[5].items()}, sched)
-        return state, log
-
-    @property
-    def _whole_run(self):
-        return True
-
     def _run_compiled(self, state, client_data, rng, batch_size, n_epochs):
         from repro.core.strategies import engine as ENG
-        if ENG.empty_run(client_data, batch_size, self.drop_remainder):
-            return None                        # empty run: per-epoch path
         if self.participation is not None:
             return self._run_participation(state, client_data, rng,
                                            batch_size, n_epochs)
         tel = self._tel
         place = self.placement
         with self._span("pack") as sp:
-            if place.enabled:
-                # each device holds its own hospitals' rows: the host packs
-                # the batch grid, placed hospital-sharded below
-                grid, packed = ENG.pack_run(client_data, batch_size, rng,
-                                            n_epochs, self.drop_remainder,
-                                            pad_clients=place.n_pad,
-                                            span=self._span)
-                inputs = (grid,)
-                example = {k: v[0, 0, 0] for k, v in grid.items()}
-                self._pack_span(sp, grid, n_epochs * packed.mask.size,
-                                n_epochs * sum(packed.n_batches))
-                del grid
-            else:
-                # the program gathers every batch from the hospitals' own
-                # arrays; the accounting reads only a batch's shape
-                data, idx, packed = ENG.pack_run_index(
-                    client_data, batch_size, rng, n_epochs,
-                    self.drop_remainder, pad_clients=place.n_pad,
-                    span=self._span)
-                inputs = (data, idx)
-                example = {k: np.zeros((batch_size, *v[0].shape[1:]),
-                                       v[0].dtype) for k, v in data.items()}
-                slots = n_epochs * packed.mask.size
-                self._pack_span(sp, {**data, "index": idx}, slots,
-                                n_epochs * sum(packed.n_batches),
-                                device_gather=slots)
+            inputs, example, packed, counters = ENG.pack_run_inputs(
+                client_data, batch_size, rng, n_epochs, self.drop_remainder,
+                place, span=self._span)
+            if sp is not None:
+                sp.set(**counters)
         sched = schedule_array(self.schedule, packed.n_batches)
         sync_w = place.client_weights() if place.padded else None
         build = functools.partial(
@@ -328,7 +240,9 @@ class SplitLearning(Strategy):
             batches, pack = ENG.pack_participation_run(
                 client_data, batch_size, rng, n_epochs, part,
                 self.drop_remainder, span=self._span)
-            self._pack_span(sp, batches, pack.mask.size, pack.mask.sum())
+            if sp is not None:
+                sp.set(**ENG.pack_counters(batches, pack.mask.size,
+                                           pack.mask.sum()))
         nbs = pack.n_batches
         full_sched = schedule_array(self.schedule, nbs)
         S_N = len(full_sched)
@@ -342,7 +256,7 @@ class SplitLearning(Strategy):
                            if int(c) in slot_of])
         steps_max = max((len(r) for r in rounds), default=0)
         if steps_max == 0:
-            return None
+            return state, []       # no sampled hospital has a batch
         sched = np.zeros((n_epochs, steps_max, 3), np.int32)
         key_idx = np.zeros((n_epochs, steps_max), np.uint32)
         base0 = self._key_step
@@ -419,7 +333,7 @@ class SplitLearning(Strategy):
                 self._record_wire_epoch(example, counts, client_set=ids)
         return logs
 
-    def _account_compiled(self, example, packed, batch_size, n_epochs=1):
+    def _account_compiled(self, example, packed, batch_size, n_epochs):
         """Analytic accounting for the compiled path: the DP accountant
         composes each hospital's step count in one call, and the transport
         meters each step at its TRUE batch shape — full batches in one
@@ -458,6 +372,11 @@ class SplitLearning(Strategy):
                                     self.name.rsplit("_", 1)[0],
                                     self.schedule, n_batches,
                                     client_set=client_set)
+
+    def _empty_epoch(self, state, client_data, rng, batch_size):
+        log = super()._empty_epoch(state, client_data, rng, batch_size)
+        self._end_of_epoch(state)              # SFLv2 still syncs clients
+        return log
 
     def _end_of_epoch(self, state):
         pass

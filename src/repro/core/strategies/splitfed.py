@@ -114,10 +114,7 @@ class SplitFedV3(SplitLearning):
             {k: np.asarray(v, np.float64)[None]
              for k, v in metrics.items()}, self.n_clients)[0]
 
-    def run_epoch(self, state, client_data, rng, batch_size):
-        if self.engine == "compiled":
-            return self._run_epoch_compiled(state, client_data, rng,
-                                            batch_size)
+    def _run_epoch_stepwise(self, state, client_data, rng, batch_size):
         tel = self._tel
         step3 = self._step3 if tel is None else self._get_obs(
             "_step3_obs", tel,
@@ -166,69 +163,11 @@ class SplitFedV3(SplitLearning):
                  for k in (met_vals[0] if met_vals else {})})
         return state, log
 
-    def _run_epoch_compiled(self, state, client_data, rng, batch_size):
-        from repro.core.strategies import engine as ENG
-        tel = self._tel
-        place = self.placement
-        with self._span("pack") as sp:
-            packed = ENG.pack_epoch(client_data, batch_size, rng, True,
-                                    pad_clients=place.n_pad,
-                                    span=self._span)
-            self._pack_span(sp, packed.batches, packed.mask.size,
-                            sum(packed.n_batches))
-        self._check_batches(packed.n_batches[:self.n_clients], batch_size)
-        steps = packed.nb_max
-        if tel is None:
-            if not hasattr(self, "_epoch_c"):
-                self._epoch_c = ENG.make_sflv3_epoch(
-                    self.adapter, self._opt_c, self._opt_s, place.c_pad,
-                    self.transport, self.privacy,
-                    client_weights=(place.client_weights() if place.padded
-                                    else None),
-                    placement=place)
-            epoch_fn = self._epoch_c
-        else:
-            epoch_fn = self._get_obs(
-                "_epoch_obs_c", tel,
-                lambda: ENG.make_sflv3_epoch(
-                    self.adapter, self._opt_c, self._opt_s, place.c_pad,
-                    self.transport, self.privacy,
-                    client_weights=(place.client_weights() if place.padded
-                                    else None),
-                    placement=place, telemetry=tel))
-        b_idx = np.stack([[s % nb if nb else 0 for nb in packed.n_batches]
-                          for s in range(steps)]).astype(np.int32)
-        key_idx = (self._take_key_indices(steps) if self._keyed
-                   else np.zeros((steps,), np.uint32))
-        batches = place.put(packed.batches)
-        sc = place.put(state["stacked_clients"])
-        c_opt = place.put(state["c_opt"])
-        out = self._enqueue(epoch_fn, (
-            sc, state["server"], c_opt, state["s_opt"], batches,
-            place.put(b_idx, axis=1), key_idx, self._privacy_base_key()),
-            stash=False)
-        (state["stacked_clients"], state["server"], state["c_opt"],
-         state["s_opt"], losses) = out[:5]
-        losses = self._wait(losses)
-        with self._account_span():
-            flat = losses[:, :self.n_clients].reshape(-1).tolist()
-            self._account_v3(
-                {k: v[0, 0] for k, v in packed.batches.items()}, packed,
-                batch_size)
-            self._end_of_epoch(state)
-            log = EpochLog(flat, steps,
-                           client_steps=[steps] * self.n_clients)
-            if tel is not None:
-                log.telemetry = self._sync_round_telemetry(
-                    tel, losses,
-                    {k: np.asarray(v) for k, v in out[5].items()})
-        return state, log
-
-    def _account_v3(self, example, packed, batch_size, n_epochs=1):
+    def _account_v3(self, example, packed, batch_size, n_epochs):
         """Analytic accounting: every client is touched every synchronous
         step (wrap-around resampling included), so the per-epoch count is
         simply ``steps = nb_max`` for DP and transport alike.  ``example``
-        is hospital 0's first batch."""
+        is a full batch; only its shapes are read."""
         steps = packed.nb_max
         for c in range(self.n_clients):
             self._dp_account(c, packed.n_samples[c], batch_size,
@@ -239,9 +178,9 @@ class SplitFedV3(SplitLearning):
         for _ in range(n_epochs):
             self._record_wire_epoch(example, packed.n_batches)
 
-    @property
-    def _whole_run(self):
-        return True
+    def _empty_epoch(self, state, client_data, rng, batch_size):
+        # batch-synchronous: a run without a batch has no hospital with one
+        self._check_batches([0] * self.n_clients, batch_size)
 
     def _run_compiled(self, state, client_data, rng, batch_size, n_epochs):
         from repro.core.strategies import engine as ENG
@@ -251,31 +190,11 @@ class SplitFedV3(SplitLearning):
         tel = self._tel
         place = self.placement
         with self._span("pack") as sp:
-            if place.enabled:
-                # each device holds its own hospitals' rows: the host packs
-                # the batch grid, placed hospital-sharded below
-                grid, packed = ENG.pack_run(client_data, batch_size, rng,
-                                            n_epochs, True,
-                                            pad_clients=place.n_pad,
-                                            span=self._span)
-                inputs = (grid,)
-                example = {k: v[0, 0, 0] for k, v in grid.items()}
-                self._pack_span(sp, grid, n_epochs * packed.mask.size,
-                                n_epochs * sum(packed.n_batches))
-                del grid
-            else:
-                # the program gathers every batch from the hospitals' own
-                # arrays; hospital 0's rows come first, so its first batch
-                # is idx[0, 0, 0] of its own array
-                data, idx, packed = ENG.pack_run_index(
-                    client_data, batch_size, rng, n_epochs, True,
-                    pad_clients=place.n_pad, span=self._span)
-                inputs = (data, idx)
-                example = {k: v[0][idx[0, 0, 0]] for k, v in data.items()}
-                slots = n_epochs * packed.mask.size
-                self._pack_span(sp, {**data, "index": idx}, slots,
-                                n_epochs * sum(packed.n_batches),
-                                device_gather=slots)
+            inputs, example, packed, counters = ENG.pack_run_inputs(
+                client_data, batch_size, rng, n_epochs, True, place,
+                span=self._span)
+            if sp is not None:
+                sp.set(**counters)
         self._check_batches(packed.n_batches[:self.n_clients], batch_size)
         steps = packed.nb_max
         if tel is None:
@@ -355,8 +274,9 @@ class SplitFedV3(SplitLearning):
             batches, pack = ENG.pack_participation_run(
                 client_data, batch_size, rng, n_epochs, part, True,
                 span=self._span)
-            self._pack_span(sp, batches, pack.mask.size,
-                            pack.mask.sum())
+            if sp is not None:
+                sp.set(**ENG.pack_counters(batches, pack.mask.size,
+                                           pack.mask.sum()))
         nbs = pack.n_batches
         self._check_batches(nbs, batch_size)
         NB_N, S = pack.nb_max, pack.n_slots

@@ -366,13 +366,13 @@ def test_split_device_gather_skips_a_hospital_without_rows(tiny_setup):
 @pytest.mark.parametrize("method", RUN_METHODS)
 def test_run_is_one_program(method, tiny_setup):
     """A 3-epoch compiled run is ONE XLA dispatch: the whole-run program
-    is invoked exactly once, traced/compiled exactly once, and no
-    per-epoch program is ever built."""
+    is invoked exactly once, traced/compiled exactly once, and no other
+    training program is dispatched."""
     clients, adapter = tiny_setup
     st, _, logs = _whole_run(method, "compiled", clients, adapter)
     assert len(logs) == 3
     assert getattr(st, "_run_calls", 0) == 1
-    assert not hasattr(st, "_epoch_c"), "per-epoch program was dispatched"
+    assert st._dispatches == 1, "a program other than the run was dispatched"
     run_fn = getattr(st, "_run_c", None) or getattr(st, "_run3_c", None)
     assert run_fn is not None
     if hasattr(run_fn, "_cache_size"):
@@ -380,9 +380,37 @@ def test_run_is_one_program(method, tiny_setup):
     # a second run reuses the same compiled program (no retrace)
     st.run(st.setup(jax.random.key(1)), [c.train for c in clients],
            np.random.default_rng(1), 4, 3)
-    assert st._run_calls == 2
+    assert st._run_calls == st._dispatches == 2
     if hasattr(run_fn, "_cache_size"):
         assert run_fn._cache_size() == 1
+
+
+@pytest.mark.parametrize("method", RUN_METHODS)
+def test_run_epoch_is_a_one_round_run(method, tiny_setup):
+    """Compiled ``run_epoch`` is ``run(n_epochs=1)``: one whole-run
+    dispatch of the same program, and from the same state and rng seed
+    the same state, log and rng draws, bit for bit."""
+    clients, adapter = tiny_setup
+    data = [c.train for c in clients]
+    st = make_strategy(method, adapter, lambda: O.adam(1e-3), len(clients))
+    rng_a = np.random.default_rng(0)
+    state, log = st.run_epoch(st.setup(jax.random.key(0)), data, rng_a, 4)
+    assert st._run_calls == st._dispatches == 1
+    got = [np.asarray(l) for i in range(len(clients))
+           for l in jax.tree.leaves(st.params_for_eval(state, i))]
+    rng_b = np.random.default_rng(0)
+    state, logs = st.run(st.setup(jax.random.key(0)), data, rng_b, 4, 1)
+    assert st._run_calls == st._dispatches == 2
+    run_fn = getattr(st, "_run_c", None) or getattr(st, "_run3_c", None)
+    if hasattr(run_fn, "_cache_size"):
+        assert run_fn._cache_size() == 1        # one program for both
+    assert logs == [log]
+    assert rng_a.bit_generator.state == rng_b.bit_generator.state
+    want = [np.asarray(l) for i in range(len(clients))
+            for l in jax.tree.leaves(st.params_for_eval(state, i))]
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_abstract_args_copies_nothing_to_the_device(monkeypatch):
@@ -430,12 +458,62 @@ def test_run_secagg_falls_back_to_per_round(tiny_setup):
 
 
 def test_run_empty_epochs(tiny_setup):
+    """Zero epochs train nothing.  So does a compiled run in which no
+    hospital has a full batch, under ``run`` and ``run_epoch`` alike: it
+    compiles nothing, draws the shuffles a pack would, and logs one empty
+    epoch per epoch (SFLv2 still averages its client segments); SFLv3
+    refuses it, and a participating run returns no epochs."""
+    from repro.core.participation import Participation
     clients, adapter = tiny_setup
     st = make_strategy("fl", adapter, lambda: O.adam(1e-3), len(clients))
     state = st.setup(jax.random.key(0))
     state, logs = st.run(state, [c.train for c in clients],
                          np.random.default_rng(0), 4, 0)
     assert logs == []
+
+    # 3 rows a hospital, 9 pooled: no batch of 10 anywhere
+    short = [{k: v[:3] for k, v in c.train.items()} for c in clients]
+    n = len(short)
+    empty = {"fl": EpochLog([], 0, client_steps=[0] * n),
+             "centralized": EpochLog([], 0),
+             "sl_am": EpochLog([], 0, client_steps=[0] * n),
+             "sflv2_ac": EpochLog([], 0, client_steps=[0] * n)}
+    for method in RUN_METHODS:
+        for epochs in (2, None):               # run(2), then run_epoch
+            st = make_strategy(method, adapter, lambda: O.adam(1e-3), n)
+            state = st.setup(jax.random.key(0))
+            rng = np.random.default_rng(0)
+            if method == "sflv3_ac":
+                with pytest.raises(ValueError, match="fewer than batch"):
+                    if epochs is None:
+                        st.run_epoch(state, short, rng, 10)
+                    else:
+                        st.run(state, short, rng, 10, epochs)
+                continue
+            if epochs is None:
+                state, log = st.run_epoch(state, short, rng, 10)
+                logs = [log]
+            else:
+                state, logs = st.run(state, short, rng, 10, epochs)
+            assert logs == [empty[method]] * (epochs or 1)
+            assert st._dispatches == 0
+            ref = np.random.default_rng(0)
+            pools = [9] if method == "centralized" else [3] * n
+            for _ in range(epochs or 1):
+                for rows in pools:
+                    ref.shuffle(np.arange(rows))
+            assert rng.bit_generator.state == ref.bit_generator.state
+            if method == "sflv2_ac":            # the end-of-epoch sync ran
+                for i in range(1, n):
+                    for a, b in zip(
+                            jax.tree.leaves(st.params_for_eval(state, 0)),
+                            jax.tree.leaves(st.params_for_eval(state, i))):
+                        np.testing.assert_array_equal(np.asarray(a),
+                                                      np.asarray(b))
+    st = make_strategy("fl", adapter, lambda: O.adam(1e-3), n,
+                       participation=Participation(n_global=n, k=2))
+    state = st.setup(jax.random.key(0))
+    assert st.run(state, short, np.random.default_rng(0), 10, 2)[1] == []
 
 
 @pytest.mark.parametrize("method", ["fl", "sl_am"])

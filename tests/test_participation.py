@@ -247,6 +247,9 @@ def test_unsupported_combinations_raise(tiny_setup):
     with pytest.raises(ValueError, match="secagg"):
         mk("fl", participation=part,
            privacy=PrivacyConfig(secagg=True))
+    from repro.core.aggregate import SecAggregator
+    with pytest.raises(ValueError, match="scan_compatible"):
+        mk("fl", participation=part, aggregator=SecAggregator(None))
     with pytest.raises(ValueError, match="n_global"):
         mk("fl", participation=Participation(n_global=5, k=2))
     # split family: fixed-size cohorts only, no observe
@@ -255,6 +258,24 @@ def test_unsupported_combinations_raise(tiny_setup):
     from repro.obs import Telemetry
     with pytest.raises(ValueError, match="observe"):
         mk("sl_ac", participation=part, observe=Telemetry())
+
+
+@pytest.mark.parametrize("method", ["fl", "sl_am", "sflv3_ac"])
+def test_run_epoch_refuses_participation(method, tiny_setup):
+    """Each ``run_epoch`` call would be round 0 again (the same cohort,
+    accounted at the sampled rate), so a participating strategy refuses
+    it before it draws from ``rng`` or dispatches; ``run`` numbers its
+    rounds."""
+    clients, adapter = tiny_setup
+    st = make_strategy(method, adapter, lambda: O.adam(1e-3), 3,
+                       participation=Participation(n_global=3, k=2))
+    state = st.setup(jax.random.key(0))
+    rng = np.random.default_rng(0)
+    with pytest.raises(ValueError, match=r"run\(n_epochs"):
+        st.run_epoch(state, [c.train for c in clients], rng, 4)
+    assert st._dispatches == 0
+    assert rng.bit_generator.state == \
+        np.random.default_rng(0).bit_generator.state
 
 
 # ---------------------------------------------------------------------------

@@ -95,7 +95,8 @@ def test_fedavg_single_client_identity(tiny_setup):
     ad = cnn_adapter(build_densenet(cfg))
     st = make_strategy("fl", ad, lambda: O.adam(1e-3), 1)
     state = st.setup(jax.random.key(0))
-    p0 = state["params"]
+    # a copy: the epoch's program donates the state's buffers
+    p0 = jax.tree.map(jnp.copy, state["params"])
     state, _ = st.run_epoch(state, [clients[0].train],
                             np.random.default_rng(0), 8)
 
